@@ -1,5 +1,6 @@
 """Framework bench: Pallas kernels vs jnp oracles — correctness max-err
-(interpret mode) and XLA-path wall time per call on this CPU."""
+of the kernel bodies (interpret mode, asked for explicitly) and the
+XLA-path wall time per call on the device JAX runs on."""
 from __future__ import annotations
 
 import time
@@ -24,6 +25,9 @@ def run(fast=True):
     import jax
     import jax.numpy as jnp
     from repro.kernels import attention, ssd, waterfill, ref
+    from repro.kernels.flash_attention import flash_attention
+    from repro.kernels.ssd import ssd_scan
+    from repro.kernels.waterfill import waterfill_batch
 
     rng = np.random.default_rng(0)
     rows = []
@@ -36,8 +40,8 @@ def run(fast=True):
         v = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
         xla = jax.jit(lambda q, k, v: attention(q, k, v, causal=True))
         t = _time(xla, q, k, v)
-        o_p = attention(q, k, v, causal=True, use_pallas=True,
-                        blk_q=64, blk_k=64)
+        o_p = flash_attention(q, k, v, causal=True, blk_q=64, blk_k=64,
+                              interpret=True)
         err = float(jnp.max(jnp.abs(o_p - ref.attention_ref(q, k, v))))
         flops = 4.0 * B * Hq * S * S * D / 2
         name = f"attn_B{B}H{Hq}S{S}D{D}"
@@ -54,7 +58,7 @@ def run(fast=True):
         Dm = jnp.ones((H,), jnp.float32)
         xla = jax.jit(lambda *a: ssd(*a))
         t = _time(xla, x, dt, A, Bm, Cm, Dm)
-        y_p = ssd(x, dt, A, Bm, Cm, Dm, use_pallas=True, blk_l=64)
+        y_p = ssd_scan(x, dt, A, Bm, Cm, Dm, blk_l=64, interpret=True)
         err = float(jnp.max(jnp.abs(y_p - ref.ssd_ref(x, dt, A, Bm, Cm, Dm))))
         name = f"ssd_B{Bt}L{L}H{H}"
         print(f"kernels/{name},{t * 1e6:.0f},{err:.2e}")
@@ -67,7 +71,7 @@ def run(fast=True):
         caps = jnp.full((Bt, W), 100.0, jnp.float32)
         xla = jax.jit(lambda *a: waterfill(*a))
         t = _time(xla, src, dst, act, caps, caps)
-        r_p = waterfill(src, dst, act, caps, caps, use_pallas=True)
+        r_p = waterfill_batch(src, dst, act, caps, caps, interpret=True)
         err = float(jnp.max(jnp.abs(
             r_p - ref.waterfill_ref(src, dst, act, caps, caps))))
         name = f"waterfill_B{Bt}F{F}W{W}"

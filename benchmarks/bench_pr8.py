@@ -30,9 +30,12 @@ sections:
   backing the same numbers.
 
 Output: ``BENCH_PR8.json`` at the repo root (override with ``--json``)
-plus a copy under ``--out`` for the CI artifact.  Re-execs itself under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when fewer
-devices are visible.  CLI::
+plus a copy under ``--out`` for the CI artifact.  A chip belongs to one
+process at a time, so the parent touches no JAX backend until its
+worker processes are done; it then runs the scaling and streaming
+sections itself over the devices it sees — on the CPU platform 8
+forced host devices (``XLA_FLAGS``, set before the backend starts and
+inert on an accelerator).  CLI::
 
     PYTHONPATH=src python -m benchmarks.bench_pr8 --min-scaling 3.0
 """
@@ -44,17 +47,14 @@ import os
 import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 import numpy as np
-import jax
 
+# jax and repro.core.vectorized are imported inside the functions:
+# importing the simulator starts the JAX backend, and the parent must
+# not hold the chip while its worker processes need it
 from repro.core import MiB
-from repro.core.graphs import make_graph, survey_names
-from repro.core.vectorized import (BucketedGridRunner, ShardedGridRunner,
-                                   trace_counter)
-from repro.core.vectorized.sim import _points_arrays
 
 DEFAULT_JSON = "BENCH_PR8.json"
 FORCE_DEVICES = 8
@@ -65,7 +65,7 @@ SLICES = {
     "mini": dict(graphs=["fork1", "merge_neighbours"],
                  schedulers=["blevel", "random", "etf", "greedy"],
                  netmodels=["maxmin", "simple"], n_workers=4, cores=2),
-    "survey": dict(graphs=list(survey_names(1)),
+    "survey": dict(graphs=None,          # survey_names(1), resolved late
                    schedulers=["blevel", "random", "etf", "greedy"],
                    netmodels=["maxmin", "simple"], n_workers=8, cores=4),
 }
@@ -75,30 +75,38 @@ POINTS = [dict(imode=im, bandwidth=bw * MiB, msd=0.0,
           for im in ("exact", "user") for bw in (32, 100)]
 
 
-def _ensure_devices(argv):
-    """Re-exec with 8 forced host devices when the platform shows
-    fewer — the scaling section needs the full mesh."""
-    if len(jax.devices()) >= FORCE_DEVICES:
-        return
-    if os.environ.get("BENCH_PR8_REEXEC"):
-        raise RuntimeError(f"re-exec still sees {len(jax.devices())} "
-                           f"devices; XLA_FLAGS not honoured?")
-    flags = (os.environ.get("XLA_FLAGS", "") +
-             f" --xla_force_host_platform_device_count={FORCE_DEVICES}")
-    env = dict(os.environ, XLA_FLAGS=flags.strip(), BENCH_PR8_REEXEC="1")
-    os.execvpe(sys.executable,
-               [sys.executable, "-m", "benchmarks.bench_pr8", *argv], env)
+def _force_host_devices():
+    """8 forced host devices for the in-process sections.  XLA reads the
+    flag when the backend starts, so this must run before anything
+    touches JAX; it only affects the CPU platform."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count="
+            f"{FORCE_DEVICES}").strip()
+
+
+def _graphs(slice_name):
+    graphs = SLICES[slice_name]["graphs"]
+    if graphs is None:
+        from repro.core.graphs import survey_names
+        graphs = list(survey_names(1))
+    return graphs
 
 
 def _entries(slice_name):
+    from repro.core.graphs import make_graph
+
     sl = SLICES[slice_name]
-    entries = [(make_graph(n, seed=0), None) for n in sl["graphs"]]
+    entries = [(make_graph(n, seed=0), None) for n in _graphs(slice_name)]
     return entries, sl["schedulers"][0], sl["n_workers"], sl["cores"]
 
 
 def _full(runner, points):
     """Un-sliced SimResult[K, B, N] with the host-side prep included —
     the per-call work a survey pays."""
+    from repro.core.vectorized.sim import _points_arrays
+
     pts, M, DD, BW, SD = _points_arrays(points)
     D = np.stack([runner._estimates(p["imode"])[0] for p in pts], axis=1)
     S = np.stack([runner._estimates(p["imode"])[1] for p in pts], axis=1)
@@ -125,13 +133,17 @@ def _assert_bitwise(ref, res, label):
 
 
 def bench_scaling(slice_name, reps):
+    import jax
+    from repro.core.vectorized import (BucketedGridRunner,
+                                       ShardedGridRunner, trace_counter)
+
     entries, sched, W, cores = _entries(slice_name)
     vm = BucketedGridRunner(entries, sched, W, cores)
     ref, wall_v = _timed(vm, reps)
     G = ref.makespan[0].size                     # B*N grid points, K=1
     rows = {"vmap": {"devices": 1, "wall_s": round(wall_v, 4),
                      "grid_points_per_s": round(G / wall_v, 1)}}
-    for D in (1, 2, 4, 8):
+    for D in (d for d in (1, 2, 4, 8) if d <= len(jax.devices())):
         with trace_counter() as tc:
             r = ShardedGridRunner(entries, sched, W, cores, devices=D)
             res, wall = _timed(r, reps)
@@ -146,11 +158,13 @@ def bench_scaling(slice_name, reps):
 
 
 def bench_streaming(slice_name, reps):
+    from repro.core.vectorized import ShardedGridRunner, trace_counter
+
     entries, sched, W, cores = _entries(slice_name)
-    single = ShardedGridRunner(entries, sched, W, cores, devices=8)
+    single = ShardedGridRunner(entries, sched, W, cores)
     ref, wall_1 = _timed(single, reps)
     with trace_counter() as tc:
-        chunked = ShardedGridRunner(entries, sched, W, cores, devices=8,
+        chunked = ShardedGridRunner(entries, sched, W, cores,
                                     stream_rows=8)
         res, wall_c = _timed(chunked, reps)
     _assert_bitwise(ref, res, "streaming/stream_rows=8")
@@ -211,19 +225,25 @@ def _run_worker(cfg):
     return json.loads(out.stdout.splitlines()[-1])
 
 
-def bench_workers(slice_name, cache_root=None):
+def bench_workers(slice_name, cache_dir=None):
     """Fresh-process service measurements: the time a survey worker
     takes from exec to the full request's results — every (scheduler,
     netmodel) compile group of the slice — cold vs persistently-cached
-    warm.  The cache lives outside the artifact directory — only its
+    warm.  The cache (default ``<checkout>/.jax_cache/bench_pr8``) is
+    emptied first and lives outside the artifact directory — only its
     hit/miss counts are part of the record."""
+    from repro.core.vectorized.engine import CACHE_ENV, DEFAULT_CACHE_DIR
+
+    if os.environ.get(CACHE_ENV):
+        raise RuntimeError(f"the worker section empties its own compile "
+                           f"cache to measure a cold worker; unset "
+                           f"{CACHE_ENV} to run it")
     sl = SLICES[slice_name]
     n_groups = len(sl["schedulers"]) * len(sl["netmodels"])
-    if cache_root is None:
-        cache_root = tempfile.gettempdir()
-    cache_dir = os.path.join(cache_root, "xla_cache_pr8")
+    if cache_dir is None:
+        cache_dir = os.path.join(DEFAULT_CACHE_DIR, "bench_pr8")
     shutil.rmtree(cache_dir, ignore_errors=True)
-    base = {"graphs": sl["graphs"], "schedulers": sl["schedulers"],
+    base = {"graphs": _graphs(slice_name), "schedulers": sl["schedulers"],
             "netmodels": sl["netmodels"], "n_workers": sl["n_workers"],
             "cores": sl["cores"], "force_devices": FORCE_DEVICES}
     rows = {}
@@ -266,7 +286,6 @@ def bench_workers(slice_name, cache_root=None):
 
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
-    _ensure_devices(argv)
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default="results",
                     help="artifact output directory (default 'results')")
@@ -280,19 +299,22 @@ def main(argv=None):
                     help="fail unless workers.grid_throughput_x reaches "
                          "this factor (the ISSUE-8 gate is 3.0)")
     args = ap.parse_args(argv)
+    _force_host_devices()
+    t0 = time.time()
+    workers = bench_workers(args.slice)      # before this process holds JAX
+    import jax
+
     record = {"generated_by": "benchmarks.bench_pr8",
               "backend": jax.default_backend(),
+              "device_kind": jax.devices()[0].device_kind,
               "slice": args.slice,
               "n_devices": len(jax.devices()),
               "cpu_count": os.cpu_count(),
-              "grid_points": (len(SLICES[args.slice]["graphs"])
-                              * len(POINTS))}
-    t0 = time.time()
+              "grid_points": len(_graphs(args.slice)) * len(POINTS)}
     record["scaling"] = bench_scaling(args.slice, args.reps)
     record["streaming"] = bench_streaming(args.slice, args.reps)
     os.makedirs(args.out, exist_ok=True)
-    record["workers"] = bench_workers(args.slice)
-    w = record["workers"]
+    record["workers"] = w = workers
     record["compile_time"] = {
         "cold_sharded_wall_s": w["cold_sharded"]["wall_s"],
         "warm_sharded_wall_s": w["warm_sharded"]["wall_s"],

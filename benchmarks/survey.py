@@ -63,8 +63,8 @@ import numpy as np
 from repro.core import MiB, parse_cluster
 from repro.core.graphs import encode_graph_batch, survey_names
 from repro.core.vectorized import (DynamicGridRunner, cache_counter,
-                                   exec_counter, make_grid_runner,
-                                   trace_counter)
+                                   compile_cache_root, exec_counter,
+                                   make_grid_runner, trace_counter)
 from repro.workloads import w_bucket
 
 from .common import geomean, time_reference_twin, write_csv
@@ -295,9 +295,12 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     persistent-cache hits (``cache_dir``) are counted separately
     (``cache_hits``/``cache_misses``) so cached XLA loads are never
     mistaken for fresh traces.  With a populated executable store
-    (``<cache_dir>/exec``, sharded engine) a group may skip tracing
+    (``<cache root>/exec``, sharded engine) a group may skip tracing
     altogether — those loads are counted as ``exec_hits`` and the gate
-    checks ``traces + exec_hits == groups``."""
+    checks ``traces + exec_hits == groups``; executables the store
+    failed to persist are ``exec_save_errors``.  ``stats["group_walls"]``
+    holds each group's first-call seconds (compile or cache load, plus
+    the run) and ``stats["device"]`` names what the grid ran on."""
     points = grid_points(grid)
     dataset, names, t_edges = dataset_axis(grid)
     encoded, groups = encode_graph_batch(names, seed=0, bucket=True,
@@ -305,6 +308,7 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     wgroups = cluster_groups(grid["clusters"])
     rows = []
     runners = {}                 # only the agreement slice is retained
+    group_walls = {}             # first call (compile + run) per group
     est_caches = [{} for _ in groups]    # shared per bucket, not per runner
     with trace_counter() as tc, cache_counter() as cc, \
             exec_counter() as xc:                        # no cross-sweep bleed
@@ -322,6 +326,8 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
                         t0 = time.perf_counter()
                         ms, xfer = runner(points)  # compile+run [K, B, N]
                         cold_s = time.perf_counter() - t0
+                        group_walls[f"{grp.label}/W{wb}/{sched}/"
+                                    f"{netmodel}"] = cold_s
                         if (wb == wgroups[0][0]
                                 and netmodel == grid["netmodels"][0]):
                             runners[(sched, netmodel, gi)] = (runner, cold_s,
@@ -344,6 +350,9 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
         cache_misses=cc.misses,
         exec_hits=xc.hits,
         exec_misses=xc.misses,
+        exec_save_errors=xc.save_errors,
+        group_walls=group_walls,
+        device=device_info(),
     )
     stats["diagnose"] = _make_diagnose(runners, grid)
     agree_rows = (agreement_pass(grid, points, encoded, groups, runners,
@@ -355,8 +364,20 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     return rows, agree_rows, stats
 
 
+def device_info():
+    """The device the grid ran on, as JAX reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
 def report(rows, agree_rows, stats):
     """Print the benchmark-driver ``name,us_per_call,derived`` rows."""
+    dev = stats["device"]
+    print(f"# device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
     for a in agree_rows:
         if a["graph_name"] == "__pergraph_path__":
             print(f"survey/bucket_vs_pergraph_cold,"
@@ -374,6 +395,7 @@ def report(rows, agree_rows, stats):
     print(f"survey/cache_hits,0,{stats.get('cache_hits', 0)}")
     print(f"survey/cache_misses,0,{stats.get('cache_misses', 0)}")
     print(f"survey/exec_hits,0,{stats.get('exec_hits', 0)}")
+    print(f"survey/exec_save_errors,0,{stats.get('exec_save_errors', 0)}")
     print(f"survey/bucket_groups,0,{stats['bucket_groups']}")
     print(f"survey/cluster_groups,0,{len(stats['cluster_groups'])}")
     print(f"survey/rows,0,{len(rows)}")
@@ -444,9 +466,10 @@ def main():
                     help="sharded engine: double-buffered chunk size in "
                          "grid rows (default: whole grid in one batch)")
     ap.add_argument("--cache-dir", default=None,
-                    help="enable JAX's persistent compilation cache at "
-                         "this directory (warm worker restarts skip all "
-                         "XLA compiles)")
+                    help="persistent compilation cache directory (warm "
+                         "worker restarts skip all XLA compiles; default "
+                         "$JAX_COMPILATION_CACHE_DIR, else .jax_cache in "
+                         "the checkout; the variable wins when set)")
     args = ap.parse_args()
     grid = dict(FULL_GRID if args.full else MINI_GRID,
                 dataset=args.dataset)
@@ -455,7 +478,8 @@ def main():
                                      agreement=not args.no_agreement,
                                      engine=args.engine, devices=args.devices,
                                      stream_rows=args.stream_rows,
-                                     cache_dir=args.cache_dir)
+                                     cache_dir=compile_cache_root(
+                                         args.cache_dir))
     report(rows, agree_rows, stats)
     print(f"# survey[{stats['dataset']}/{stats['engine']}]: {len(rows)} "
           f"grid points, {stats['compiles']} jit "

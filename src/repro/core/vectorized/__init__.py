@@ -11,7 +11,7 @@ from .sim import (make_simulator, simulate_batch,
                   DOWNLOAD_SLOTS, PAIR_SLOTS, SimResult)
 from .api import SimConfig, build, build_for_graph, make_grid_runner
 from .engine import (ShardedGridRunner, DoubleBufferQueue,
-                     enable_compile_cache, cache_counter,
+                     enable_compile_cache, compile_cache_root, cache_counter,
                      cache_event_counts, ExecutableStore, exec_counter)
 from .scheduling import (VEC_SCHEDULERS, make_vec_scheduler,
                          make_bucket_scheduler,
@@ -37,7 +37,8 @@ __all__ = ["GraphSpec", "BucketedGraphSpec", "BucketGroup", "encode_graph",
            "DOWNLOAD_SLOTS", "PAIR_SLOTS", "SimResult",
            "SimConfig", "build", "build_for_graph", "make_grid_runner",
            "ShardedGridRunner", "DoubleBufferQueue",
-           "enable_compile_cache", "cache_counter", "cache_event_counts",
+           "enable_compile_cache", "compile_cache_root", "cache_counter",
+           "cache_event_counts",
            "ExecutableStore", "exec_counter",
            "VEC_SCHEDULERS", "make_vec_scheduler", "make_bucket_scheduler",
            "bucket_ready_tasks", "frontier_mask",

@@ -51,7 +51,8 @@ class SimConfig:
     ``stream_rows``-row double-buffered chunking); ``cache_dir``
     enables JAX's persistent compilation cache for *every* entry point
     that sees the config, so warm worker processes skip XLA
-    compilation entirely."""
+    compilation entirely (``$JAX_COMPILATION_CACHE_DIR``, when set,
+    takes precedence — ``engine.compile_cache_root``)."""
 
     flow_slots: bool | None = None
     frontier: bool | None = None
@@ -119,7 +120,8 @@ def build(spec=None, *, n_workers: int, cores=None, scheduler=None,
             and isinstance(bspec.n_inputs, np.ndarray)):
         # the spec is concrete, so widen the shape-derived caps to the
         # root count — all roots are ready at t=0 (specs.py)
-        cfg = cfg.replace(frontier_caps=frontier_caps_for_spec(bspec))
+        cfg = cfg.replace(frontier_caps=frontier_caps_for_spec(
+            bspec, n_workers=n_workers))
     if bspec is not None and cores is not None:
         # host-side guard: a task that fits no worker would stall the
         # event loop — raise here like the reference scheduler base
@@ -180,7 +182,7 @@ def make_grid_runner(entries, scheduler, n_workers, cores, *,
 
         runner = make_grid_runner(entries, "blevel", 8, cores2d,
                                   engine="sharded", devices=8,
-                                  cache_dir="~/.cache/repro-xla")
+                                  cache_dir=".jax_cache")
         ms, xfer = runner(points)          # [K, B, N], sharded
 
     ``engine="vmap"`` (default) returns a plain ``BucketedGridRunner``;
@@ -188,12 +190,14 @@ def make_grid_runner(entries, scheduler, n_workers, cores, *,
     ``devices`` mesh devices with optional ``stream_rows`` chunking.
     ``cache_dir`` enables the persistent compilation cache either way,
     and for the sharded engine additionally an ``ExecutableStore``
-    under ``<cache_dir>/exec`` — a warm worker then skips tracing
-    entirely (DESIGN.md §9)."""
+    under ``<cache root>/exec`` — a warm worker then skips tracing
+    entirely (DESIGN.md §9).  ``$JAX_COMPILATION_CACHE_DIR``, when
+    set, is the cache root whatever ``cache_dir`` says."""
     cfg = _merge_config(config, opts)
+    cache_root = None
     if cfg.cache_dir is not None:
         from .engine import enable_compile_cache
-        enable_compile_cache(cfg.cache_dir)
+        cache_root = enable_compile_cache(cfg.cache_dir)
     kwargs = dict(netmodel=netmodel, shape=shape, batch=batch,
                   est_cache=est_cache,
                   max_steps=cfg.max_steps if max_steps is None else max_steps)
@@ -203,9 +207,8 @@ def make_grid_runner(entries, scheduler, n_workers, cores, *,
     if cfg.engine == "sharded":
         import os
         from .engine import ShardedGridRunner
-        exec_dir = (None if cfg.cache_dir is None else
-                    os.path.join(os.path.expanduser(str(cfg.cache_dir)),
-                                 "exec"))
+        exec_dir = (None if cache_root is None else
+                    os.path.join(cache_root, "exec"))
         return ShardedGridRunner(entries, scheduler, n_workers, cores,
                                  devices=cfg.devices,
                                  stream_rows=cfg.stream_rows,

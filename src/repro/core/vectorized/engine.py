@@ -22,7 +22,7 @@ the device count or chunking.  Warm starts come in two tiers:
   fresh-vs-cached XLA compiles are counted by ``cache_counter`` (jit
   traces and cache misses are distinct odometers — a tier-1 warm
   worker shows ``traces == groups, misses == 0``).
-* ``ExecutableStore`` (``exec_dir=``, or ``<cache_dir>/exec`` via
+* ``ExecutableStore`` (``exec_dir=``, or ``<cache root>/exec`` via
   ``make_grid_runner``) persists the *serialized compiled executable*
   per (program identity, argument shapes) key, so a tier-2 warm worker
   skips tracing too — it deserializes and runs: ``traces == 0,
@@ -40,14 +40,14 @@ import pickle
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...launch.mesh import make_grid_mesh
 from .sim import BucketedGridRunner
 
 __all__ = ["ShardedGridRunner", "DoubleBufferQueue", "make_sharded_rows_fn",
-           "enable_compile_cache", "cache_counter", "cache_event_counts",
+           "enable_compile_cache", "compile_cache_root", "cache_counter",
+           "cache_event_counts",
            "ExecutableStore", "exec_counter"]
 
 
@@ -60,13 +60,14 @@ def make_sharded_rows_fn(run, mesh):
     traces the very program ``ShardedGridRunner`` compiles."""
     # per row: vmap the K cluster signatures; per shard: vmap the
     # local rows; shard_map splits the row axis across devices.  No
-    # collectives — each device's slice is independent, so check_rep
-    # is moot (and must be off for the while_loop body).
+    # collectives — each device's slice is independent, so the
+    # varying-manual-axes check is moot (and must be off for the
+    # while_loop body).
     over_clusters = jax.vmap(run, in_axes=(None,) * 7 + (0,))
     over_rows = jax.vmap(over_clusters, in_axes=(0,) * 7 + (None,))
-    return shard_map(over_rows, mesh=mesh,
-                     in_specs=(P("grid"),) * 7 + (P(),),
-                     out_specs=P("grid"), check_rep=False)
+    return jax.shard_map(over_rows, mesh=mesh,
+                         in_specs=(P("grid"),) * 7 + (P(),),
+                         out_specs=P("grid"), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -97,25 +98,51 @@ def _install_cache_listener():
     _LISTENER[0] = True
 
 
-def enable_compile_cache(path) -> None:
-    """Point JAX's persistent compilation cache at ``path`` and drop the
-    size/time floors so every simulator program is cached (our programs
-    are small but cost seconds of XLA time).  A long-lived worker — or a
-    restarted one — then answers survey requests with zero cold
-    compiles: the second process pays tracing only and loads binaries
-    from ``path``.  Idempotent; also installs the hit/miss listener so
-    ``cache_counter`` works.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: a fixed path, because the directory is part of
+# what makes a later process find the entries (gitignored)
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), *[os.pardir] * 4,
+    ".jax_cache"))
+
+
+def compile_cache_root(cache_dir=None) -> str:
+    """The one place the compile-cache directory is decided:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (and then no code sets
+    another), else an explicit ``cache_dir``, else ``DEFAULT_CACHE_DIR``
+    inside the checkout.  ``ExecutableStore`` entries live under
+    ``<root>/exec``."""
+    root = os.environ.get(CACHE_ENV) or (
+        DEFAULT_CACHE_DIR if cache_dir is None else cache_dir)
+    return os.path.abspath(os.path.expanduser(str(root)))
+
+
+def enable_compile_cache(cache_dir=None) -> str:
+    """Turn on JAX's persistent compilation cache at
+    ``compile_cache_root(cache_dir)`` and drop the size/time floors so
+    every simulator program is cached (our programs are small but cost
+    seconds of XLA time).  A long-lived worker — or a restarted one —
+    then answers survey requests with zero cold compiles: the second
+    process pays tracing only and loads binaries from the cache.
+    Idempotent; also installs the hit/miss listener so ``cache_counter``
+    works.  Returns the cache root.
 
     The cache *singleton* latches on the first compile of the process —
-    a dir configured afterwards is silently ignored — so this resets it
-    (``compilation_cache.reset_cache``) to make enabling safe at any
-    point, not just before the first jit."""
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    a dir configured afterwards is silently ignored — so a change of
+    directory resets it (``compilation_cache.reset_cache``) to make
+    enabling safe at any point, not just before the first jit.  Under
+    ``$JAX_COMPILATION_CACHE_DIR`` JAX already holds that directory and
+    nothing is reconfigured."""
+    root = compile_cache_root(cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    from jax.experimental.compilation_cache import compilation_cache
-    compilation_cache.reset_cache()
+    if not os.environ.get(CACHE_ENV) and \
+            jax.config.jax_compilation_cache_dir != root:
+        jax.config.update("jax_compilation_cache_dir", root)
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
     _install_cache_listener()
+    return root
 
 
 def cache_event_counts() -> dict:
@@ -166,7 +193,7 @@ class cache_counter:
 # zero traces, zero XLA compiles.
 
 _EXEC_FORMAT = 1                 # bump to invalidate persisted entries
-_EXEC_EVENTS = {"hits": 0, "misses": 0}
+_EXEC_EVENTS = {"hits": 0, "misses": 0, "save_errors": 0}
 
 
 class exec_counter:
@@ -174,12 +201,15 @@ class exec_counter:
     ``cache_counter``: ``with exec_counter() as xc: ...; xc.hits,
     xc.misses``.  A *hit* loaded a serialized executable (no trace, no
     XLA compile); a *miss* fell through to trace + compile (and then
-    populated the store).  In-process reuse of an already-resolved
-    executable counts nothing."""
+    populated the store); a *save error* is a compiled executable the
+    store failed to persist, so the next worker will miss too.
+    In-process reuse of an already-resolved executable counts
+    nothing."""
 
     def __enter__(self):
         self._h0 = _EXEC_EVENTS["hits"]
         self._m0 = _EXEC_EVENTS["misses"]
+        self._e0 = _EXEC_EVENTS["save_errors"]
         return self
 
     def __exit__(self, *exc):
@@ -192,6 +222,10 @@ class exec_counter:
     @property
     def misses(self) -> int:
         return _EXEC_EVENTS["misses"] - self._m0
+
+    @property
+    def save_errors(self) -> int:
+        return _EXEC_EVENTS["save_errors"] - self._e0
 
 
 class ExecutableStore:
@@ -237,8 +271,8 @@ class ExecutableStore:
             with open(tmp, "wb") as f:
                 pickle.dump((payload, in_tree, out_tree), f)
             os.replace(tmp, self._file(key))
-        except Exception:
-            pass                 # best-effort cache; never fail the run
+        except Exception:        # best-effort cache; never fail the run,
+            _EXEC_EVENTS["save_errors"] += 1     # but count the miss-to-be
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +399,10 @@ class ShardedGridRunner(BucketedGridRunner):
             chunk = max(1, -(-self.stream_rows // d)) * d
         return chunk, -(-G // chunk) * chunk
 
-    def _execute(self, D, S, M, DD, BW, SD):
+    def chunk_outputs(self, D, S, M, DD, BW, SD):
+        """Run the grid; returns the device-resident ``SimResult`` of
+        each row chunk, in row order, each sharded over the mesh —
+        ``gather`` assembles them into ``SimResult[K, B, N]``."""
         tm = jax.tree_util.tree_map
         B, N = D.shape[:2]
         G = B * N
@@ -402,10 +439,21 @@ class ShardedGridRunner(BucketedGridRunner):
             if i == 0 and self._store is not None:
                 fn = self._resolve_exec(batch, clusters_dev)
             outs.append(fn(*batch, clusters_dev))
+        return outs
+
+    def _execute(self, D, S, M, DD, BW, SD):
+        return self.gather(self.chunk_outputs(D, S, M, DD, BW, SD),
+                           *D.shape[:2])
+
+    @staticmethod
+    def gather(outs, B, N):
+        """The host ``SimResult[K, B, N]`` of ``chunk_outputs``' chunks
+        for a grid of B graphs x N points."""
+        tm = jax.tree_util.tree_map
         res = tm(lambda *xs: np.concatenate([np.asarray(x) for x in xs],
                                             axis=0), *outs)
 
         def to_grid(x):            # [G(+pad), K] -> [K, B, N]
-            x = x[:G].reshape((B, N) + x.shape[1:])
+            x = x[:B * N].reshape((B, N) + x.shape[1:])
             return np.moveaxis(x, 2, 0)
         return tm(to_grid, res)

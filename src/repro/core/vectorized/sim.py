@@ -172,8 +172,8 @@ def _make_waterfill(waterfill_impl: str):
     caps) -> rates``.  ``"jnp"`` is the progressive-filling while_loop
     (``vectorized.waterfill`` — CPU and fallback path); ``"pallas"``
     routes through ``kernels.ops.waterfill`` so the one-hot/MXU Pallas
-    kernel runs natively on TPU (interpret mode elsewhere) with the
-    vmap batch as the Pallas grid.  ``"auto"`` picks per backend."""
+    kernel compiles natively (TPU only) with the vmap batch as the
+    Pallas grid.  ``"auto"`` picks per backend."""
     if _resolve_waterfill_impl(waterfill_impl) == "pallas":
         from ...kernels.ops import waterfill as kernel_waterfill
 
@@ -360,7 +360,7 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
         f_bytes = jnp.where(edge_valid, sizes[e_obj], 0.0)
         pair = f_src * W + f_dst
         if frontier_caps is None:
-            CF, CT = frontier_caps_for((T, O, E))
+            CF, CT = frontier_caps_for((T, O, E), n_workers=W)
         else:
             # an explicit override never exceeds the axis itself
             CF, CT = min(frontier_caps[0], E), min(frontier_caps[1], T)
@@ -1004,7 +1004,7 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             p_time0 = jnp.where(task_valid, delay, jnp.inf)
 
         if frontier_caps is None:
-            CF, CT = frontier_caps_for((T, O, E))
+            CF, CT = frontier_caps_for((T, O, E), n_workers=W)
         else:
             # an explicit override never exceeds the axis itself
             CF, CT = min(frontier_caps[0], E), min(frontier_caps[1], T)
@@ -1725,11 +1725,9 @@ class BucketedGridRunner:
             self._est[name] = (np.stack(ds), np.stack(ss))
         return self._est[name]
 
-    def __call__(self, points):
-        """Same point dicts as ``DynamicGridRunner``; returns
-        ``(makespans f32[B, N], transferred f32[B, N])`` with the graph
-        axis in ``self.names`` order — with a leading cluster axis
-        (``f32[K, B, N]``) when built with a ``[K, W]`` cores matrix."""
+    def grid_arrays(self, points):
+        """Host arrays ``(D, S, M, DD, BW, SD)`` of one call over
+        ``points`` — what ``_execute`` takes."""
         points, M, DD, BW, SD = _points_arrays(points)
         # [B, N, T] / [B, N, O]: per point the whole graph batch sees
         # that point's imode estimates
@@ -1737,7 +1735,14 @@ class BucketedGridRunner:
                       for p in points], axis=1)
         S = np.stack([self._estimates(p.get("imode", "exact"))[1]
                       for p in points], axis=1)
-        res = self._execute(D, S, M, DD, BW, SD)
+        return D, S, M, DD, BW, SD
+
+    def __call__(self, points):
+        """Same point dicts as ``DynamicGridRunner``; returns
+        ``(makespans f32[B, N], transferred f32[B, N])`` with the graph
+        axis in ``self.names`` order — with a leading cluster axis
+        (``f32[K, B, N]``) when built with a ``[K, W]`` cores matrix."""
+        res = self._execute(*self.grid_arrays(points))
         _check_ok(res.ok, f"{type(self).__name__}({self.names!r}, "
                           f"{self.scheduler!r})", res.overflow)
         ms, xfer = np.asarray(res.makespan), np.asarray(res.transferred)

@@ -250,14 +250,27 @@ def frontier_cap(n: int, floor: int = FRONTIER_FLOOR) -> int:
     return min(n, max(floor, round_up(n // 4)))
 
 
-def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR):
+# pending transfers (wanted flows waiting for a download slot) grow
+# with the number of destination workers, so the flow frontier holds at
+# least this many per worker — four DOWNLOAD_SLOTS pools.  The
+# shape-only cap overflowed on the paper's 32-worker clusters (crossvx
+# on 32x4 in the T512 bucket under blevel/maxmin needs more than 256)
+FLOW_FRONTIER_PER_WORKER = 16
+
+
+def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR,
+                      n_workers: int = 0):
     """``(flow_cap, task_cap)`` for a bucket shape ``(T, O, E)`` — the
-    derived sizes of the candidate-flow and ready-task frontiers."""
+    derived sizes of the candidate-flow and ready-task frontiers; the
+    flow cap also covers ``FLOW_FRONTIER_PER_WORKER * n_workers``."""
     T, _O, E = shape
-    return frontier_cap(E, floor), frontier_cap(T, floor)
+    flow_cap = max(frontier_cap(E, floor),
+                   FLOW_FRONTIER_PER_WORKER * n_workers)
+    return min(E, flow_cap), frontier_cap(T, floor)
 
 
-def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR):
+def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR,
+                           n_workers: int = 0):
     """Root-aware ``(flow_cap, task_cap)`` for a *concrete* spec: the
     shape-derived ``frontier_caps_for``, with the task cap raised to
     cover the graph's roots.  Every root is simultaneously ready at
@@ -265,7 +278,7 @@ def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR):
     first step (e.g. a graph of all-independent tasks); ``build`` uses
     this whenever the spec is bound at build time."""
     T, _O, E = bspec.shape
-    CF, CT = frontier_caps_for((T, _O, E), floor)
+    CF, CT = frontier_caps_for((T, _O, E), floor, n_workers)
     roots = np.asarray(bspec.task_valid) & (np.asarray(bspec.n_inputs) == 0)
     n_roots = int(np.max(np.sum(roots, axis=-1))) if roots.size else 0
     return CF, min(T, max(CT, round_up(n_roots)))

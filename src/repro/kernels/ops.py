@@ -1,24 +1,21 @@
 """Jit'd public wrappers around the Pallas kernels with XLA fallbacks.
 
-On TPU the Pallas path compiles natively; everywhere else (this CPU
-container, the dry-run's host platform) ``use_pallas=False`` (default)
-routes to the pure-jnp oracle in ``ref.py`` and ``use_pallas=True`` runs
-the kernel in interpret mode — bit-accurate kernel-body semantics for
-tests.
+``use_pallas=False`` (default) routes to the pure-jnp oracle in
+``ref.py``; ``use_pallas=True`` compiles the Pallas kernel with Mosaic,
+which needs a TPU — on any other backend the call fails instead of
+quietly running something else.  Interpret mode (bit-accurate
+kernel-body semantics on any backend) is the kernels' own
+``interpret=True`` argument, for callers that ask for it: the CPU test
+suite steers these wrappers onto it (``tests/conftest.py``).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention as _flash_pallas
 from .ssd import ssd_scan as _ssd_pallas
 from .waterfill import waterfill_batch as _waterfill_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _require_f32(op: str, **arrays) -> None:
@@ -45,8 +42,7 @@ def attention(q, k, v, *, causal=True, window=0, scale=None, kv_len=None,
     """
     if use_pallas and isinstance(window, int) and kv_len is None:
         return _flash_pallas(q, k, v, causal=causal, window=window,
-                             scale=scale, blk_q=blk_q, blk_k=blk_k,
-                             interpret=not _on_tpu())
+                             scale=scale, blk_q=blk_q, blk_k=blk_k)
     return ref.attention_ref(q, k, v, causal=causal, window=window,
                              scale=scale, kv_len=kv_len)
 
@@ -55,8 +51,7 @@ def ssd(x, dt, A, B, C, D, *, use_pallas=False, blk_l=64):
     """Mamba-2 SSD chunked scan.  Oracle: ref.ssd_ref (naive recurrence);
     the XLA path uses the chunk-parallel dual form (same math, matmuls)."""
     if use_pallas:
-        return _ssd_pallas(x, dt, A, B, C, D, blk_l=blk_l,
-                           interpret=not _on_tpu())
+        return _ssd_pallas(x, dt, A, B, C, D, blk_l=blk_l)
     return ref.ssd_chunked(x, dt, A, B, C, D, chunk=blk_l)
 
 
@@ -77,7 +72,7 @@ def waterfill(src, dst, active, caps_up, caps_down, *, use_pallas=False,
             x[None] for x in (src, dst, active, caps_up, caps_down))
     if use_pallas:
         out = _waterfill_pallas(src, dst, active, caps_up, caps_down,
-                                rounds=rounds, interpret=not _on_tpu())
+                                rounds=rounds)
     else:
         out = ref.waterfill_ref(src, dst, active, caps_up, caps_down)
     return out[0] if unbatched else out

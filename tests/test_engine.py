@@ -27,9 +27,12 @@ from repro.core.vectorized import (BucketedGridRunner, ShardedGridRunner,
                                    DoubleBufferQueue, make_grid_runner,
                                    trace_counter, cache_counter,
                                    cache_event_counts, exec_counter)
+from repro.core.vectorized.engine import (CACHE_ENV, DEFAULT_CACHE_DIR,
+                                          ExecutableStore,
+                                          compile_cache_root,
+                                          enable_compile_cache)
 from repro.core.vectorized.scheduling import (spmd_safe_argsort,
                                               spmd_safe_sort)
-from repro.core.vectorized.sim import _points_arrays
 from repro.launch.mesh import make_grid_mesh, make_test_mesh
 
 import test_vectorized_dynamic as tvd
@@ -47,12 +50,7 @@ POINTS = [dict(imode="exact", bandwidth=100 * MiB, msd=0.0,
 def full_result(runner, points):
     """The un-sliced ``SimResult[K, B, N]`` — every field, so parity
     checks cover ok/n_steps/n_events, not just the makespan."""
-    pts, M, DD, BW, SD = _points_arrays(points)
-    D = np.stack([runner._estimates(p.get("imode", "exact"))[0]
-                  for p in pts], axis=1)
-    S = np.stack([runner._estimates(p.get("imode", "exact"))[1]
-                  for p in pts], axis=1)
-    return runner._execute(D, S, M, DD, BW, SD)
+    return runner._execute(*runner.grid_arrays(points))
 
 
 def assert_bitwise(res_a, res_b):
@@ -217,15 +215,31 @@ def test_sharded_rejects_gridless_mesh():
 # ------------------------------------------------- persistent cache
 
 @pytest.fixture
-def scoped_cache_dir(tmp_path):
+def scoped_cache_dir(tmp_path, monkeypatch):
+    """A cache directory of the test's own: ``$JAX_COMPILATION_CACHE_DIR``
+    would otherwise win over it."""
     from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.delenv(CACHE_ENV, raising=False)
     old = jax.config.jax_compilation_cache_dir
     yield tmp_path
     jax.config.update("jax_compilation_cache_dir", old)
     compilation_cache.reset_cache()     # re-latch to the restored config
 
 
-def test_cache_counter_without_cache_dir():
+@pytest.fixture
+def no_cache_dir():
+    """No persistent cache directory for the test, whatever the
+    environment configured."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+    compilation_cache.reset_cache()
+
+
+def test_cache_counter_without_cache_dir(no_cache_dir):
     """Without a cache dir nothing can *hit*; fresh compiles still
     count as misses (jax's cache feature flag is on by default), which
     is what makes the miss odometer an honest fresh-compile counter."""
@@ -277,6 +291,7 @@ def test_cache_warm_worker_subprocess(tmp_path):
                           "exec_misses": xc.misses, "ms": float(ms[0][0])}))
     """)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env.pop(CACHE_ENV, None)             # the test's own cache_dir, cold
 
     def worker():
         out = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
@@ -296,7 +311,37 @@ def test_cache_warm_worker_subprocess(tmp_path):
     assert warm["ms"] == cold["ms"]
 
 
+def test_compile_cache_root_precedence(monkeypatch, tmp_path):
+    """One place decides the directory: the variable, else the explicit
+    dir, else the fixed path in the checkout — never a temp path."""
+    monkeypatch.delenv(CACHE_ENV, raising=False)
+    assert compile_cache_root() == DEFAULT_CACHE_DIR
+    assert os.path.dirname(DEFAULT_CACHE_DIR) == ROOT
+    assert compile_cache_root(tmp_path) == str(tmp_path)
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path / "env"))
+    assert compile_cache_root() == str(tmp_path / "env")
+    assert compile_cache_root(tmp_path) == str(tmp_path / "env")
+
+
+def test_enable_compile_cache_keeps_env_dir(monkeypatch, tmp_path):
+    """Under ``$JAX_COMPILATION_CACHE_DIR`` no code sets another
+    directory: an explicit ``cache_dir`` loses to the variable."""
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path / "env"))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache(tmp_path / "explicit") == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+
+
 # ------------------------------------------------- executable store
+
+def test_exec_store_save_error_is_counted(tmp_path):
+    """A failed save never fails the run, but it is counted — the next
+    worker will miss, and the survey prints the count."""
+    store = ExecutableStore(tmp_path)
+    with exec_counter() as xc:
+        store.save(("k",), object())     # not a compiled executable
+    assert xc.save_errors == 1 and not any(tmp_path.iterdir())
+
 
 def test_exec_store_roundtrip_in_process(tmp_path):
     """Tier-2 warm start without leaving the process: a second runner
@@ -363,7 +408,6 @@ def test_eight_device_parity_subprocess():
         from repro.core.graphs import make_graph
         from repro.core.vectorized import (BucketedGridRunner,
                                            ShardedGridRunner, trace_counter)
-        from repro.core.vectorized.sim import _points_arrays
         assert len(jax.devices()) == 8
 
         POINTS = [dict(imode="exact", bandwidth=100 * MiB, msd=0.0,
@@ -374,12 +418,7 @@ def test_eight_device_parity_subprocess():
                        decision_delay=0.0, seed=7)]
 
         def full(runner, points):
-            pts, M, DD, BW, SD = _points_arrays(points)
-            D = np.stack([runner._estimates(p["imode"])[0] for p in pts],
-                         axis=1)
-            S = np.stack([runner._estimates(p["imode"])[1] for p in pts],
-                         axis=1)
-            return runner._execute(D, S, M, DD, BW, SD)
+            return runner._execute(*runner.grid_arrays(points))
 
         entries = [(make_graph("fork1", seed=0), None),
                    (make_graph("merge_neighbours", seed=0), None)]

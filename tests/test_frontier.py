@@ -36,7 +36,8 @@ from repro.core.vectorized import (SimConfig, build, build_for_graph,
 from repro.core.vectorized.scheduling import (bucket_ready_tasks,
                                               frontier_mask,
                                               make_vec_scheduler)
-from repro.core.vectorized.specs import (FRONTIER_FLOOR, as_bucketed,
+from repro.core.vectorized.specs import (FLOW_FRONTIER_PER_WORKER,
+                                         FRONTIER_FLOOR, as_bucketed,
                                          frontier_cap, frontier_caps_for,
                                          frontier_caps_for_spec)
 
@@ -179,6 +180,31 @@ def test_frontier_cap_derivation():
     cf, ct = frontier_caps_for((2048, 576, 2016))
     assert ct == frontier_cap(2048) and cf == frontier_cap(2016)
     assert (cf, ct) == (512, 512)          # the T2048 bench caps
+
+
+@pytest.mark.parametrize("W,want_cf", [(8, 256), (16, 256), (32, 512),
+                                       (128, 992)])
+def test_flow_cap_grows_with_workers(W, want_cf):
+    """Pending transfers grow with the destination count: the flow cap
+    holds ``FLOW_FRONTIER_PER_WORKER`` per worker, never beyond E; the
+    task cap does not depend on W."""
+    cf, ct = frontier_caps_for((512, 320, 992), n_workers=W)
+    assert cf == want_cf and ct == frontier_cap(512)
+    assert cf >= min(992, FLOW_FRONTIER_PER_WORKER * W)
+
+
+def test_w32_survey_graph_fits_the_flow_frontier():
+    """crossvx on the paper's 32x4 cluster in the T512 bucket under
+    blevel/maxmin overflowed the shape-only flow cap (256); the
+    worker-scaled cap holds it, so the runner does not raise."""
+    from repro.core import MiB
+    from repro.core.vectorized import BucketedGridRunner
+
+    runner = BucketedGridRunner([(make_graph("crossvx", seed=0), None)],
+                                "blevel", 32, 4, shape=(512, 320, 992))
+    ms, _ = runner([dict(imode="exact", bandwidth=32 * MiB, msd=0.0,
+                         decision_delay=0.0)])
+    assert np.isfinite(ms).all()
 
 
 def test_frontier_mask_and_bucket_ready_tasks():

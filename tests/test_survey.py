@@ -142,3 +142,40 @@ def test_counter_hash_matches_vectorized_twin():
         for c, h in zip(ctrs, np.asarray(jx)):
             for n in (1, 2, 3, 8):
                 assert counter_choice(int(s), int(c), n) == int(h) % n
+
+
+def test_device_info_names_the_backend():
+    import jax
+
+    dev = survey.device_info()
+    assert dev == {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())}
+
+
+def test_chip_smoke_grid_is_one_w32_group_of_24_points():
+    """The smoke slice: FULL_GRID's points, the paper's widest clusters
+    in one W=32 group, 2 schedulers x 2 netmodels."""
+    import chip_smoke
+
+    grid = chip_smoke.smoke_grid()
+    assert len(survey.grid_points(grid)) == 24
+    (w, names, cores), = survey.cluster_groups(grid["clusters"])
+    assert w == 32 and names == ["32x4", "32x16"] and cores.shape == (2, 32)
+    assert len(grid["schedulers"]) * len(grid["netmodels"]) == 4
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """On the CPU the smoke test exits non-zero before any work and
+    prints no result line."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode != 0
+    assert out.stdout == ""
+    assert "no TPU" in out.stderr
