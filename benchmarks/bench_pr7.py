@@ -97,7 +97,7 @@ def bench_static(reps=5):
                                  scheduler="blevel"))(d, s, bw)
         aw_p = pad_to(np.asarray(aw), shape[0], 0).astype(np.int32)
         prio_p = pad_to(np.asarray(prio), shape[0], 0.0).astype(np.float32)
-        cf, ct = frontier_caps_for(shape)
+        cf, ct = frontier_caps_for(shape, n_workers=W)
         row = {"graph": g.name, "cluster": cname, "edges": int(spec.E),
                "frontier_caps": {"CF": cf, "CT": ct}}
         for key, flag in (("baseline", False), ("frontier", True)):

@@ -32,7 +32,7 @@ point-wise:
 * JX106 — ready-frontier bounds (DESIGN.md §3): frontier targets must
   carry the ``int32[CT]`` task frontier (and, in slot mode, the
   ``int32[CF]`` flow-candidate frontier) with ``(CF, CT) =
-  frontier_caps_for(shape)``, and a frontier slot-mode loop may not
+  frontier_caps_for(shape, n_workers=W)``, and a frontier slot-mode loop may not
   carry *any* ``[E]``-shaped state — the frontier+slot combination is
   exactly the mode whose event loop owns no per-edge arrays.  Checked
   on a dedicated bucket shape where the derived caps collide with no
@@ -374,7 +374,7 @@ def default_targets(n_workers: int = 4, shape=(32, 64, 96)):
     fr_shape = (1280, 192, 2048)
     Tf, Of, Ef = fr_shape
     fr_spec = abstract_spec(fr_shape)
-    fr_caps = frontier_caps_for(fr_shape)
+    fr_caps = frontier_caps_for(fr_shape, n_workers=W)
     for netmodel in ("maxmin", "simple"):
         run = make_bucket_simulator(W, None, netmodel, max_cores=4)
         targets.append(Target(

@@ -252,14 +252,19 @@ def frontier_cap(n: int, floor: int = FRONTIER_FLOOR) -> int:
 
 # pending transfers (wanted flows waiting for a download slot) grow
 # with the number of destination workers, so the flow frontier holds at
-# least this many per worker — four DOWNLOAD_SLOTS pools.  The
-# shape-only cap overflowed on the paper's 32-worker clusters (crossvx
-# on 32x4 in the T512 bucket under blevel/maxmin needs more than 256)
+# least this many per worker — four DOWNLOAD_SLOTS pools.  No tighter
+# bound exists: every (object, destination) key of the bucket can be
+# pending at once, so only E bounds the list.  This is a sizing rule,
+# and an overflow still fails loudly.  The shape-only cap (256 for
+# E=992) overflowed on the paper's 32-worker clusters.  Over the full
+# survey grid on 32x4 (T512 bucket, maxmin, all six schedulers, all 24
+# points), the worst case is crossvx: it fits 320 under blevel and mcp,
+# 384 under random, and 256 under the rest, against 16 * 32 = 512
 FLOW_FRONTIER_PER_WORKER = 16
 
 
-def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR,
-                      n_workers: int = 0):
+def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR, *,
+                      n_workers: int):
     """``(flow_cap, task_cap)`` for a bucket shape ``(T, O, E)`` — the
     derived sizes of the candidate-flow and ready-task frontiers; the
     flow cap also covers ``FLOW_FRONTIER_PER_WORKER * n_workers``."""
@@ -269,8 +274,8 @@ def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR,
     return min(E, flow_cap), frontier_cap(T, floor)
 
 
-def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR,
-                           n_workers: int = 0):
+def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR, *,
+                            n_workers: int):
     """Root-aware ``(flow_cap, task_cap)`` for a *concrete* spec: the
     shape-derived ``frontier_caps_for``, with the task cap raised to
     cover the graph's roots.  Every root is simultaneously ready at
@@ -278,7 +283,7 @@ def frontier_caps_for_spec(bspec, floor: int = FRONTIER_FLOOR,
     first step (e.g. a graph of all-independent tasks); ``build`` uses
     this whenever the spec is bound at build time."""
     T, _O, E = bspec.shape
-    CF, CT = frontier_caps_for((T, _O, E), floor, n_workers)
+    CF, CT = frontier_caps_for((T, _O, E), floor, n_workers=n_workers)
     roots = np.asarray(bspec.task_valid) & (np.asarray(bspec.n_inputs) == 0)
     n_roots = int(np.max(np.sum(roots, axis=-1))) if roots.size else 0
     return CF, min(T, max(CT, round_up(n_roots)))

@@ -160,8 +160,8 @@ def test_root_aware_caps_cover_all_roots_graphs():
     g = make_graph("duration_stairs", seed=0)
     spec = encode_graph(g)
     bspec = as_bucketed(spec)
-    cf_shape, ct_shape = frontier_caps_for(bspec.shape)
-    cf, ct = frontier_caps_for_spec(bspec)
+    cf_shape, ct_shape = frontier_caps_for(bspec.shape, n_workers=8)
+    cf, ct = frontier_caps_for_spec(bspec, n_workers=8)
     n_roots = int(np.sum(np.asarray(bspec.n_inputs) == 0))
     assert n_roots > ct_shape          # the shape-only cap would overflow
     assert cf == cf_shape and ct_shape < ct <= spec.T and ct >= n_roots
@@ -176,8 +176,8 @@ def test_frontier_cap_derivation():
     big = frontier_cap(2048)
     assert FRONTIER_FLOOR <= big < 2048
     # the simlint JX106 shape: caps distinct from every axis
-    assert frontier_caps_for((1280, 192, 2048)) == (512, 320)
-    cf, ct = frontier_caps_for((2048, 576, 2016))
+    assert frontier_caps_for((1280, 192, 2048), n_workers=4) == (512, 320)
+    cf, ct = frontier_caps_for((2048, 576, 2016), n_workers=4)
     assert ct == frontier_cap(2048) and cf == frontier_cap(2016)
     assert (cf, ct) == (512, 512)          # the T2048 bench caps
 
