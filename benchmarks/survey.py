@@ -64,7 +64,8 @@ from repro.core import MiB, parse_cluster
 from repro.core.graphs import encode_graph_batch, survey_names
 from repro.core.vectorized import (DynamicGridRunner, cache_counter,
                                    compile_cache_root, exec_counter,
-                                   make_grid_runner, trace_counter)
+                                   make_grid_runner, setup_timer,
+                                   trace_counter)
 from repro.workloads import w_bucket
 
 from .common import geomean, time_reference_twin, write_csv
@@ -298,7 +299,9 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     (``<cache root>/exec``, sharded engine) a group may skip tracing
     altogether — those loads are counted as ``exec_hits`` and the gate
     checks ``traces + exec_hits == groups``; executables the store
-    failed to persist are ``exec_save_errors``.  ``stats["group_walls"]``
+    failed to persist are ``exec_save_errors``.  ``stats["setup_s"]``
+    splits the sweep's set-up seconds into tracing, compiling and the
+    runners' host work (``setup_timer``); ``stats["group_walls"]``
     holds each group's first-call seconds (compile or cache load, plus
     the run) and ``stats["device"]`` names what the grid ran on."""
     points = grid_points(grid)
@@ -311,7 +314,7 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     group_walls = {}             # first call (compile + run) per group
     est_caches = [{} for _ in groups]    # shared per bucket, not per runner
     with trace_counter() as tc, cache_counter() as cc, \
-            exec_counter() as xc:                        # no cross-sweep bleed
+            exec_counter() as xc, setup_timer() as st:   # no cross-sweep bleed
         for wb, cnames, cores2d in wgroups:
             for sched in grid["schedulers"]:
                 for netmodel in grid["netmodels"]:
@@ -351,6 +354,7 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
         exec_hits=xc.hits,
         exec_misses=xc.misses,
         exec_save_errors=xc.save_errors,
+        setup_s=st.seconds,
         group_walls=group_walls,
         device=device_info(),
     )
@@ -396,6 +400,8 @@ def report(rows, agree_rows, stats):
     print(f"survey/cache_misses,0,{stats.get('cache_misses', 0)}")
     print(f"survey/exec_hits,0,{stats.get('exec_hits', 0)}")
     print(f"survey/exec_save_errors,0,{stats.get('exec_save_errors', 0)}")
+    for phase, secs in stats.get("setup_s", {}).items():
+        print(f"survey/setup_{phase}_s,0,{secs:.3f}")
     print(f"survey/bucket_groups,0,{stats['bucket_groups']}")
     print(f"survey/cluster_groups,0,{len(stats['cluster_groups'])}")
     print(f"survey/rows,0,{len(rows)}")

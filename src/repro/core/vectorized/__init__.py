@@ -6,13 +6,13 @@ from .specs import frontier_cap, frontier_caps_for
 from .sim import (make_simulator, simulate_batch,
                   make_dynamic_simulator, simulate_dynamic_grid,
                   make_bucket_simulator, make_bucket_dynamic_simulator,
-                  DynamicGridRunner, BucketedGridRunner, jit_trace_count,
-                  reset_trace_count, trace_counter,
-                  DOWNLOAD_SLOTS, PAIR_SLOTS, SimResult)
+                  DynamicGridRunner, BucketedGridRunner, trace_counter,
+                  DOWNLOAD_SLOTS, PAIR_SLOTS, SIM_PHASES, SimResult)
 from .api import SimConfig, build, build_for_graph, make_grid_runner
 from .engine import (ShardedGridRunner, DoubleBufferQueue,
                      enable_compile_cache, compile_cache_root, cache_counter,
-                     cache_event_counts, ExecutableStore, exec_counter)
+                     cache_event_counts, SETUP_PHASES, setup_seconds,
+                     setup_timer, ExecutableStore, exec_counter)
 from .scheduling import (VEC_SCHEDULERS, make_vec_scheduler,
                          make_bucket_scheduler,
                          bucket_ready_tasks, frontier_mask,
@@ -32,14 +32,13 @@ __all__ = ["GraphSpec", "BucketedGraphSpec", "BucketGroup", "encode_graph",
            "make_simulator", "simulate_batch",
            "make_dynamic_simulator", "simulate_dynamic_grid",
            "make_bucket_simulator", "make_bucket_dynamic_simulator",
-           "DynamicGridRunner", "BucketedGridRunner", "jit_trace_count",
-           "reset_trace_count", "trace_counter",
-           "DOWNLOAD_SLOTS", "PAIR_SLOTS", "SimResult",
+           "DynamicGridRunner", "BucketedGridRunner", "trace_counter",
+           "DOWNLOAD_SLOTS", "PAIR_SLOTS", "SIM_PHASES", "SimResult",
            "SimConfig", "build", "build_for_graph", "make_grid_runner",
            "ShardedGridRunner", "DoubleBufferQueue",
            "enable_compile_cache", "compile_cache_root", "cache_counter",
-           "cache_event_counts",
-           "ExecutableStore", "exec_counter",
+           "cache_event_counts", "SETUP_PHASES", "setup_seconds",
+           "setup_timer", "ExecutableStore", "exec_counter",
            "VEC_SCHEDULERS", "make_vec_scheduler", "make_bucket_scheduler",
            "bucket_ready_tasks", "frontier_mask",
            "make_static_blevel_scheduler", "make_static_tlevel_scheduler",

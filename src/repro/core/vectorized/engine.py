@@ -34,9 +34,12 @@ Run under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to get
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import pickle
+import threading
+import time
 
 import jax
 import numpy as np
@@ -47,7 +50,8 @@ from .sim import BucketedGridRunner
 
 __all__ = ["ShardedGridRunner", "DoubleBufferQueue", "make_sharded_rows_fn",
            "enable_compile_cache", "compile_cache_root", "cache_counter",
-           "cache_event_counts",
+           "cache_event_counts", "SETUP_PHASES", "setup_span",
+           "setup_seconds", "setup_timer",
            "ExecutableStore", "exec_counter"]
 
 
@@ -181,6 +185,104 @@ class cache_counter:
 
 
 # ---------------------------------------------------------------------------
+# set-up seconds by phase
+#
+# ``trace``: JAX tracing a program to a jaxpr and lowering it to MLIR;
+# ``compile``: XLA compiles, compile-cache fetches, executable-store
+# loads and saves; ``host``: the runner's own host work (encoding,
+# padding and stacking graphs, imode estimates).  JAX reports its
+# compile steps through jax.monitoring, a start scalar when a step opens
+# and a duration when it closes; the program's own steps are
+# ``setup_span``s.  Steps nest (a jnp function traced inside a program
+# reports its own trace, the cache fetch sits inside the backend
+# compile), so only a step that opens while no other is open counts,
+# and no second is counted twice.  Same accumulate-and-diff scheme as
+# ``cache_counter``.
+
+SETUP_PHASES = ("trace", "compile", "host")
+# jax's log_elapsed_time steps: a start scalar, then a duration
+_JAX_STEPS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+# a duration alone, reported from inside the backend compile step
+_JAX_CACHE_FETCH = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SETUP_SECONDS = dict.fromkeys(SETUP_PHASES, 0.0)
+_OPEN_STEPS = threading.local()          # .depth: open steps, per thread
+
+
+def _open_depth() -> int:
+    return getattr(_OPEN_STEPS, "depth", 0)
+
+
+def _step_closed(phase: str, seconds: float, opened: bool) -> None:
+    """A step of ``phase`` ended: close it if it had opened, and count
+    it when no step is left open around it."""
+    depth = max(_open_depth() - 1, 0) if opened else _open_depth()
+    _OPEN_STEPS.depth = depth
+    if depth == 0:
+        _SETUP_SECONDS[phase] += seconds
+
+
+def _on_jax_step_start(event, value, **kwargs):
+    if event in _JAX_STEPS:
+        _OPEN_STEPS.depth = _open_depth() + 1
+
+
+def _on_jax_step_duration(event, duration, **kwargs):
+    if event in _JAX_STEPS:
+        _step_closed(_JAX_STEPS[event], duration, True)
+    elif event == _JAX_CACHE_FETCH:
+        _step_closed("compile", duration, False)
+
+
+jax.monitoring.register_scalar_listener(_on_jax_step_start)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_step_duration)
+
+
+@contextlib.contextmanager
+def setup_span(phase: str):
+    """A set-up step of the program's own: a profiler span
+    ``survey.<phase>``, so a trace taken around set-up shows it on the
+    device trace's clock, whose seconds add to ``phase`` of
+    ``setup_seconds``."""
+    if phase not in SETUP_PHASES:
+        raise KeyError(f"set-up phase {phase!r} is not one of "
+                       f"{SETUP_PHASES}")
+    _OPEN_STEPS.depth = _open_depth() + 1
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(f"survey.{phase}"):
+            yield
+    finally:
+        _step_closed(phase, time.perf_counter() - t0, True)
+
+
+def setup_seconds() -> dict:
+    """Process-total set-up seconds by phase (``SETUP_PHASES``)."""
+    return dict(_SETUP_SECONDS)
+
+
+class setup_timer:
+    """Scoped set-up accounting, mirroring ``cache_counter``: ``with
+    setup_timer() as st: ...; st.seconds`` is ``{phase: seconds}``
+    spent inside the block (valid during and after it).  Nests safely
+    — delta-based, never resets the process totals."""
+
+    def __enter__(self):
+        self._s0 = setup_seconds()
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    @property
+    def seconds(self) -> dict:
+        return {p: _SETUP_SECONDS[p] - self._s0[p] for p in SETUP_PHASES}
+
+
+# ---------------------------------------------------------------------------
 # tier-2 warm start: the serialized-executable store
 #
 # The persistent XLA cache (above) kills recompiles but a fresh process
@@ -192,7 +294,9 @@ class cache_counter:
 # identity + argument avals, so a warm worker deserializes and calls —
 # zero traces, zero XLA compiles.
 
-_EXEC_FORMAT = 1                 # bump to invalidate persisted entries
+# bump to invalidate persisted entries; 2: the loop's named phases
+# (SIM_PHASES) ride in the executable's HLO
+_EXEC_FORMAT = 2
 _EXEC_EVENTS = {"hits": 0, "misses": 0, "save_errors": 0}
 
 
@@ -254,9 +358,9 @@ class ExecutableStore:
         from jax.experimental.serialize_executable import \
             deserialize_and_load
         try:
-            with open(self._file(key), "rb") as f:
+            with setup_span("compile"), open(self._file(key), "rb") as f:
                 payload, in_tree, out_tree = pickle.load(f)
-            loaded = deserialize_and_load(payload, in_tree, out_tree)
+                loaded = deserialize_and_load(payload, in_tree, out_tree)
         except Exception:
             _EXEC_EVENTS["misses"] += 1
             return None
@@ -266,11 +370,12 @@ class ExecutableStore:
     def save(self, key, compiled) -> None:
         from jax.experimental.serialize_executable import serialize
         try:
-            payload, in_tree, out_tree = serialize(compiled)
-            tmp = self._file(key) + f".tmp{os.getpid()}"
-            with open(tmp, "wb") as f:
-                pickle.dump((payload, in_tree, out_tree), f)
-            os.replace(tmp, self._file(key))
+            with setup_span("compile"):
+                payload, in_tree, out_tree = serialize(compiled)
+                tmp = self._file(key) + f".tmp{os.getpid()}"
+                with open(tmp, "wb") as f:
+                    pickle.dump((payload, in_tree, out_tree), f)
+                os.replace(tmp, self._file(key))
         except Exception:        # best-effort cache; never fail the run,
             _EXEC_EVENTS["save_errors"] += 1     # but count the miss-to-be
 

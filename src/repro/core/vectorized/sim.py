@@ -90,6 +90,16 @@ NEG_TIME = jnp.float32(-1e30)
 DOWNLOAD_SLOTS = 4
 PAIR_SLOTS = 2
 
+# The dynamic event loop's phases, as ``jax.named_scope`` names: the
+# compiler keeps them in every HLO instruction's ``op_name``, so a device
+# trace can charge each op to its phase.  ``sim.schedule``: applying due
+# assignments, scheduler invocations and the static schedule before the
+# loop; ``sim.ready``: detecting ready flows and tasks and starting them;
+# ``sim.rates``: the network model's flow rates (the max-min waterfill);
+# ``sim.advance``: the next event time, progress and completions.
+SIM_PHASES = ("sim.schedule", "sim.ready", "sim.rates", "sim.advance")
+SCHEDULE, READY, RATES, ADVANCE = SIM_PHASES
+
 
 class SimResult(typing.NamedTuple):
     """Uniform result of every simulator path (static, dynamic,
@@ -230,7 +240,7 @@ def _acquire_slots(st, pick, dst_e, src_e, bytes_e, W, ids=None):
 # (tracing happens exactly once per XLA compilation; eager calls are
 # filtered out via ``trace_state_clean``), so callers can assert
 # compile counts — the survey runner's one-compile-per-bucket
-# regression gate reads deltas of ``jit_trace_count()``.
+# regression gate reads it through ``trace_counter``.
 _TRACE_COUNT = [0]
 
 
@@ -241,20 +251,6 @@ def _count_trace():
     probe = getattr(jax.core, "trace_state_clean", None)
     if probe is None or not probe():
         _TRACE_COUNT[0] += 1
-
-
-def jit_trace_count() -> int:
-    """Total simulator jit traces (== compilations) so far in-process."""
-    return _TRACE_COUNT[0]
-
-
-def reset_trace_count() -> int:
-    """Zero the odometer and return the value it had — per-grid-run
-    attribution without cross-test/cross-sweep bleed (callers that only
-    ever diffed ``jit_trace_count()`` still work unchanged)."""
-    old = _TRACE_COUNT[0]
-    _TRACE_COUNT[0] = 0
-    return old
 
 
 class trace_counter:
@@ -989,19 +985,21 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
         bandwidth_ = jnp.asarray(bandwidth, jnp.float32)
         seed_ = jnp.asarray(seed, jnp.int32)
 
-        if dynamic_sched:
-            greedy_prio = rank_priorities(bucket_blevel(bspec, est_dur))
-            p_worker0 = jnp.full(T, -1, jnp.int32)
-            p_prio0 = jnp.zeros(T, jnp.float32)
-            p_time0 = jnp.full(T, jnp.inf, jnp.float32)
-        else:
-            # static schedule == the single invocation at t=0, computed
-            # from pure estimates; it reaches workers after the delay
-            aw0, prio0 = static_schedule(bspec, est_dur, est_size,
-                                         bandwidth_, seed_, cores_j)
-            p_worker0 = jnp.where(task_valid, aw0, -1)
-            p_prio0 = prio0
-            p_time0 = jnp.where(task_valid, delay, jnp.inf)
+        with jax.named_scope(SCHEDULE):
+            if dynamic_sched:
+                greedy_prio = rank_priorities(bucket_blevel(bspec, est_dur))
+                p_worker0 = jnp.full(T, -1, jnp.int32)
+                p_prio0 = jnp.zeros(T, jnp.float32)
+                p_time0 = jnp.full(T, jnp.inf, jnp.float32)
+            else:
+                # static schedule == the single invocation at t=0,
+                # computed from pure estimates; it reaches workers after
+                # the delay
+                aw0, prio0 = static_schedule(bspec, est_dur, est_size,
+                                             bandwidth_, seed_, cores_j)
+                p_worker0 = jnp.where(task_valid, aw0, -1)
+                p_prio0 = prio0
+                p_time0 = jnp.where(task_valid, delay, jnp.inf)
 
         if frontier_caps is None:
             CF, CT = frontier_caps_for((T, O, E), n_workers=W)
@@ -1322,14 +1320,26 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             return wf(jnp.clip(src_e, 0), jnp.clip(aw_e, 0), active, caps)
 
         # -------------------------------------------------------- body
-        def body(st):
+        # each iteration runs the four SIM_PHASES in order, each under
+        # its own named scope
+        def schedule(st):
             st = apply_due(st)
             if dynamic_sched:
                 st = invoke(st)
                 st = apply_due(st)           # decision_delay == 0
-            st = start_flows(st)
-            st = start_tasks(st)
-            rates = rates_of(st)
+            return st
+
+        def body(st):
+            with jax.named_scope(SCHEDULE):
+                st = schedule(st)
+            with jax.named_scope(READY):
+                st = start_tasks(start_flows(st))
+            with jax.named_scope(RATES):
+                rates = rates_of(st)
+            with jax.named_scope(ADVANCE):
+                return advance(st, rates)
+
+        def advance(st, rates):
             running = st["t_started"] & ~st["t_done"]
             t_next = jnp.min(jnp.where(running, st["t_finish"], jnp.inf))
             gran = st["now"] * 6e-7 + TIME_EPS
@@ -1380,16 +1390,24 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             return dict(st, f_rem=rem, f_done=st["f_done"] | done_now)
 
         def body_frontier(st):
-            st = apply_due(st)
-            if dynamic_sched:
-                st = invoke(st)
-                st = apply_due(st)           # decision_delay == 0
+            with jax.named_scope(SCHEDULE):
+                st = schedule(st)
+            with jax.named_scope(READY):
+                st, key_e = ready_frontier(st)
+            with jax.named_scope(RATES):
+                rates = rates_of(st)
+            with jax.named_scope(ADVANCE):
+                return advance_frontier(st, rates, key_e)
+
+        def ready_frontier(st):
+            """Returns the state and the edges' (object, destination)
+            keys, which the simple model's advance reuses."""
             # fused O(E) detection pass — the only per-edge work in the
             # loop: new (producer-done, consumer-assigned) pairs become
             # flow candidates (dedup rep pinned per key) and satisfied
             # edges; everything below runs on the bounded frontiers
             ready_t = st["in_cnt"] >= n_inputs
-            keymax = None
+            keymax = key_e = None
             if E > 0:
                 aw_e = st["aw"][e_task]
                 src_e = st["aw"][prod_task_e]
@@ -1432,8 +1450,9 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                       overflow=st["overflow"] | ov_t)
             if E > 0 and use_slots:
                 st = start_flows_frontier(st, keymax)
-            st = start_tasks_frontier(st)
-            rates = rates_of(st)
+            return start_tasks_frontier(st), key_e
+
+        def advance_frontier(st, rates, key_e):
             running = st["t_started"] & ~st["t_done"]
             t_next = jnp.min(jnp.where(running, st["t_finish"], jnp.inf))
             gran = st["now"] * 6e-7 + TIME_EPS
@@ -1627,7 +1646,7 @@ class BucketedGridRunner:
     axis, so ``__call__(points)`` executes the full [graphs x points]
     grid — estimates, msd, delay, bandwidth, seed — in a single device
     call compiled exactly once (the survey's one-compile-per-bucket
-    contract; measured by ``jit_trace_count``).
+    contract; measured by ``trace_counter``).
 
     ``cores`` is a scalar, a per-worker list (heterogeneous cluster,
     e.g. ``1x8+4x2``), or a stacked ``[K, W]`` matrix of K same-W
@@ -1646,46 +1665,51 @@ class BucketedGridRunner:
     def __init__(self, entries, scheduler, n_workers, cores,
                  netmodel="maxmin", max_steps=None, shape=None,
                  batch=None, est_cache=None):
-        if isinstance(entries, dict):
-            entries = list(entries.values())
-        entries = [(g, encode_graph(g) if s is None else s)
-                   for g, s in entries]
-        self.graphs = [g for g, _ in entries]
-        self.specs = [s for _, s in entries]
-        self.names = [g.name for g in self.graphs]
-        self.scheduler = scheduler
-        arr = np.asarray(cores)
-        if arr.ndim <= 1:
-            clusters = _resolve_cores(n_workers, cores)[None, :]
-            self._single_cluster = True
-        else:
-            clusters = arr.astype(np.int32)
-            self._single_cluster = False
-        if clusters.shape[-1] != n_workers:
-            raise ValueError(f"cores matrix is {clusters.shape[-1]} wide "
-                             f"but n_workers={n_workers}")
-        self.clusters = clusters
-        for k in range(clusters.shape[0]):
-            _check_cpus_fit(self.specs, clusters[k],
-                            f"BucketedGridRunner({scheduler!r})")
-        self.shape = tuple(shape) if shape is not None \
-            else bucket_shape(self.specs)
-        if batch is not None:
-            if batch.shape != self.shape or batch.B != len(self.specs):
-                raise ValueError(
-                    f"prebuilt batch {batch.shape}xB{batch.B} does not "
-                    f"match {self.shape}xB{len(self.specs)}")
-            self.bspec = batch
-        else:
-            self.bspec = stack_specs([pad_spec(s, self.shape)
-                                      for s in self.specs])
-        from .api import build
-        self.run = build(None, n_workers=n_workers, cores=None,
-                         scheduler=scheduler, netmodel=netmodel,
-                         dynamic=True, max_steps=max_steps,
-                         max_cores=max(int(clusters.max()), 1))
-        self._fn = self._make_fn()
-        self._est = {} if est_cache is None else est_cache
+        from .engine import setup_span
+
+        # encoding, padding and stacking the graphs and building
+        # the simulator are set-up on the host
+        with setup_span("host"):
+            if isinstance(entries, dict):
+                entries = list(entries.values())
+            entries = [(g, encode_graph(g) if s is None else s)
+                       for g, s in entries]
+            self.graphs = [g for g, _ in entries]
+            self.specs = [s for _, s in entries]
+            self.names = [g.name for g in self.graphs]
+            self.scheduler = scheduler
+            arr = np.asarray(cores)
+            if arr.ndim <= 1:
+                clusters = _resolve_cores(n_workers, cores)[None, :]
+                self._single_cluster = True
+            else:
+                clusters = arr.astype(np.int32)
+                self._single_cluster = False
+            if clusters.shape[-1] != n_workers:
+                raise ValueError(f"cores matrix is {clusters.shape[-1]} wide "
+                                 f"but n_workers={n_workers}")
+            self.clusters = clusters
+            for k in range(clusters.shape[0]):
+                _check_cpus_fit(self.specs, clusters[k],
+                                f"BucketedGridRunner({scheduler!r})")
+            self.shape = tuple(shape) if shape is not None \
+                else bucket_shape(self.specs)
+            if batch is not None:
+                if batch.shape != self.shape or batch.B != len(self.specs):
+                    raise ValueError(
+                        f"prebuilt batch {batch.shape}xB{batch.B} does not "
+                        f"match {self.shape}xB{len(self.specs)}")
+                self.bspec = batch
+            else:
+                self.bspec = stack_specs([pad_spec(s, self.shape)
+                                          for s in self.specs])
+            from .api import build
+            self.run = build(None, n_workers=n_workers, cores=None,
+                             scheduler=scheduler, netmodel=netmodel,
+                             dynamic=True, max_steps=max_steps,
+                             max_cores=max(int(clusters.max()), 1))
+            self._fn = self._make_fn()
+            self._est = {} if est_cache is None else est_cache
 
     def _make_fn(self):
         """The compiled grid program: vmap clusters K x graphs B x
@@ -1716,13 +1740,15 @@ class BucketedGridRunner:
         """Padded, stacked estimates for one imode: (f32[B, T], f32[B, O])."""
         if name not in self._est:
             from ..imodes import encode_imode
+            from .engine import setup_span
             T, O, _ = self.shape
-            ds, ss = [], []
-            for g in self.graphs:
-                d, s = encode_imode(g, name)
-                ds.append(pad_to(d, T))
-                ss.append(pad_to(s, O))
-            self._est[name] = (np.stack(ds), np.stack(ss))
+            with setup_span("host"):
+                ds, ss = [], []
+                for g in self.graphs:
+                    d, s = encode_imode(g, name)
+                    ds.append(pad_to(d, T))
+                    ss.append(pad_to(s, O))
+                self._est[name] = (np.stack(ds), np.stack(ss))
         return self._est[name]
 
     def grid_arrays(self, points):

@@ -5,7 +5,7 @@ Three contracts from the bucketing refactor:
 * padding is semantically inert — a graph padded into a larger shape
   bucket produces the same makespans and transferred bytes as the
   unpadded per-graph path, to float32 tolerance;
-* one jit compilation serves a whole bucket (``jit_trace_count``);
+* one jit compilation serves a whole bucket (``trace_counter``);
 * heterogeneous per-worker core lists (incl. zero-core padded workers)
   match the reference simulator under the existing parity tolerances.
 """
@@ -18,7 +18,6 @@ from repro.core.graphs import make_graph, survey_names, encode_graph_batch
 from repro.core.vectorized import (encode_graph, pad_spec, pad_specs,
                                    stack_specs, t_bucket, bucket_shape,
                                    BucketedGridRunner, DynamicGridRunner,
-                                   jit_trace_count, reset_trace_count,
                                    trace_counter)
 
 import test_vectorized_dynamic as tvd
@@ -134,19 +133,18 @@ def test_one_compile_serves_a_bucket():
 
 
 def test_trace_count_reset_and_nesting():
-    """``reset_trace_count`` zeroes the odometer and returns the old
-    value; ``trace_counter`` reads deltas so nested scopes and a reset
-    survivor (``jit_trace_count`` callers) stay coherent."""
+    """``trace_counter`` reads deltas of the process odometer, so nested
+    scopes each count the traces inside them and a scope opened after
+    the fact counts none of them."""
     g = tvd.mini_fork()
-    reset_trace_count()
-    assert jit_trace_count() == 0
     with trace_counter() as outer:
         with trace_counter() as inner:
             BucketedGridRunner([(g, None)], "blevel", 4, 2)(POINTS[:1])
         assert inner.count == 1
-    assert outer.count == 1
-    old = reset_trace_count()
-    assert old == 1 and jit_trace_count() == 0
+        with trace_counter() as later:
+            pass
+        assert later.count == 0
+    assert outer.count == 1 and inner.count == 1
 
 
 @pytest.mark.parametrize("cluster", ["1x4+3x2", "2x4+2x1"])

@@ -56,6 +56,18 @@ def test_check_compiles_contract():
                                    buckets=["T160xO160xE416:a"]))
 
 
+def test_report_prints_the_setup_split(capsys):
+    stats = dict(compiles=1, bucket_groups=1, cluster_groups=["W4:4x2"],
+                 dataset="default", t_edges="T_EDGES", cache_hits=0,
+                 device=dict(platform="cpu", kind="cpu", count=1),
+                 setup_s=dict(trace=1.25, compile=0.5, host=0.125))
+    survey.report([], [], stats)
+    out = capsys.readouterr().out.splitlines()
+    assert "survey/cache_hits,0,0" in out
+    assert {"survey/setup_trace_s,0,1.250", "survey/setup_compile_s,0,0.500",
+            "survey/setup_host_s,0,0.125"} <= set(out)
+
+
 def test_bucket_graph_batch_groups_survey_reps():
     """``encode_graph_batch(bucket=True)`` returns the padded groups the
     survey compiles once each; the mini representatives share one."""
