@@ -549,6 +549,11 @@ def make_bucket_greedy_placer(n_workers, cores):
     ``GreedyWorkerScheduler.schedule``.  Padded tasks are never ready, so
     they place nothing and bump no loads.  ``cores`` is traced like the
     bucket schedulers' (``None`` falls back to the build-time cluster).
+
+    The loop makes one trip per task to place, not one per task of the
+    bucket: an unplaced task changes nothing, so skipping it is exact,
+    and an invocation that places nothing costs no trip.  Under ``vmap``
+    the batch runs as many trips as its busiest lane.
     """
     cores_default = _resolve_cores(n_workers, cores)
     BIG = jnp.int32(np.iinfo(np.int32).max)
@@ -558,9 +563,13 @@ def make_bucket_greedy_placer(n_workers, cores):
         bspec = as_jax(bspec)
         cpus = bspec.cpus
 
-        def body(t, st):
-            pw, load = st
-            active = ready_unassigned[t]
+        def cond(st):
+            _, _, waiting = st
+            return jnp.any(waiting)
+
+        def body(st):
+            pw, load, waiting = st
+            t = jnp.argmax(waiting)                # smallest id left
             c = jnp.where(cores_j >= cpus[t], cost_tw[t], jnp.inf)
             # ineligible workers are inf/BIG-masked just above; the mins
             # pick among eligible candidates only
@@ -568,12 +577,12 @@ def make_bucket_greedy_placer(n_workers, cores):
             ld = jnp.where(cand, load, BIG)
             cand = cand & (ld == jnp.min(ld))  # simlint: disable=PY205
             w = jnp.argmax(cand).astype(jnp.int32)  # first = smallest id
-            pw = pw.at[t].set(jnp.where(active, w, pw[t]))
-            load = load.at[w].add(jnp.where(active, 1, 0))
-            return pw, load
+            return (pw.at[t].set(w), load.at[w].add(1),
+                    waiting.at[t].set(False))
 
-        pw, _ = jax.lax.fori_loop(
-            0, bspec.T, body, (jnp.full(bspec.T, -1, jnp.int32), load0))
+        pw, _, _ = jax.lax.while_loop(
+            cond, body,
+            (jnp.full(bspec.T, -1, jnp.int32), load0, ready_unassigned))
         return pw
 
     return place

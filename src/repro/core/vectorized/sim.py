@@ -195,7 +195,7 @@ def _make_waterfill(waterfill_impl: str):
                                                     caps, caps)
 
 
-def _acquire_slots(st, pick, dst_e, src_e, bytes_e, W, ids=None):
+def _acquire_slots(st, pick, onehot, src_e, bytes_e, ids=None):
     """Move this round's picked flows (<= 1 per destination worker —
     ``_pick_per_bucket``'s contract) into the flow-slot pool: each
     destination worker owns ``DOWNLOAD_SLOTS`` consecutive slots, and a
@@ -203,37 +203,37 @@ def _acquire_slots(st, pick, dst_e, src_e, bytes_e, W, ids=None):
     occupancy < DOWNLOAD_SLOTS, so a free slot must exist; ``overflow``
     records any violation of that invariant and poisons ``ok``.
 
-    ``pick``/``dst_e``/``src_e``/``bytes_e`` may be per-edge ``[E]`` or
-    per-frontier-candidate ``[CF]`` arrays; in the latter case ``ids``
-    supplies the real edge id per candidate (``slot_edge`` always stores
-    edge ids, whatever the pick axis)."""
-    E = pick.shape[0]
-    e_ids = jnp.arange(E, dtype=jnp.int32)
+    ``onehot`` is the ``[F, W]`` destination one-hot (``_onehot``) of
+    the pick axis, which may be per-edge ``[E]`` or per-frontier-
+    candidate ``[CF]``; in the latter case ``ids`` supplies the real
+    edge id per candidate (``slot_edge`` always stores edge ids,
+    whatever the pick axis)."""
+    W = onehot.shape[1]
     if ids is None:
-        ids = e_ids
-    # the (single) picked entry per destination worker, -1 where none —
-    # dense per-bucket max, not a scatter (see _bucket_max)
-    onehot = dst_e[:, None] == jnp.arange(W, dtype=dst_e.dtype)[None, :]
-    pe = jnp.max(jnp.where(onehot & pick[:, None], e_ids[:, None], -1),
-                 initial=-1,
-                 axis=0)
+        ids = jnp.arange(pick.shape[0], dtype=jnp.int32)
+    # each worker's (single) pick, read out by dense masked reduces —
+    # neither a scatter (see _bucket_max) nor a gather (_bucket_read):
+    # a column of ``sel`` holds at most one True, so its masked sum adds
+    # one value to zeros, exact for ints and floats alike
+    sel = onehot & pick[:, None]
+    picked_w = jnp.any(sel, axis=0)
     occ_w = (st["slot_edge"] >= 0).reshape(W, DOWNLOAD_SLOTS)
     first_free = jnp.argmin(occ_w.astype(jnp.int32), axis=1)
     has_free = ~jnp.all(occ_w, axis=1)
-    take = (pe >= 0) & has_free
-    pe_c = jnp.clip(pe, 0)
+    take = picked_w & has_free
     # dense slot write: slot (w, first_free[w]) takes worker w's pick
     put = ((jnp.arange(DOWNLOAD_SLOTS)[None, :] == first_free[:, None])
            & take[:, None]).reshape(-1)
     def spread(v):
-        return jnp.broadcast_to(v[:, None],
+        v_w = jnp.sum(jnp.where(sel, v[:, None], 0), axis=0, dtype=v.dtype)
+        return jnp.broadcast_to(v_w[:, None],
                                 (W, DOWNLOAD_SLOTS)).reshape(-1)
     return dict(
         st,
-        slot_edge=jnp.where(put, spread(ids[pe_c]), st["slot_edge"]),
-        slot_src=jnp.where(put, spread(src_e[pe_c]), st["slot_src"]),
-        slot_rem=jnp.where(put, spread(bytes_e[pe_c]), st["slot_rem"]),
-        overflow=st["overflow"] | jnp.any((pe >= 0) & ~has_free),
+        slot_edge=jnp.where(put, spread(ids), st["slot_edge"]),
+        slot_src=jnp.where(put, spread(src_e), st["slot_src"]),
+        slot_rem=jnp.where(put, spread(bytes_e), st["slot_rem"]),
+        overflow=st["overflow"] | jnp.any(picked_w & ~has_free),
     )
 
 # jit-trace odometer: every trace of a simulator ``run`` body bumps it
@@ -355,6 +355,11 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
         needed = cross & is_rep
         f_bytes = jnp.where(edge_valid, sizes[e_obj], 0.0)
         pair = f_src * W + f_dst
+        # loop-invariant worker one-hots of the tasks and the flows: the
+        # pick rounds read per-worker tables through them, and
+        # body_frontier's core release reduces over the tasks' one
+        onehot_aw = _onehot(assignment, W)
+        onehot_f = _onehot(f_dst, W)
         if frontier_caps is None:
             CF, CT = frontier_caps_for((T, O, E), n_workers=W)
         else:
@@ -439,13 +444,14 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
                     dcnt = jnp.zeros(W, jnp.int32).at[f_dst].add(af * needed)
                     pcnt = (jnp.zeros(W * W, jnp.int32)
                             .at[pair].add(af * needed))
-                eligible = (base & (dcnt[f_dst] < DOWNLOAD_SLOTS)
+                eligible = (base
+                            & (_bucket_read(onehot_f, dcnt) < DOWNLOAD_SLOTS)
                             & (pcnt[pair] < PAIR_SLOTS))
-                pick = _pick_per_bucket(f_dst, W, eligible, f_prio)
+                pick = _pick_per_bucket(onehot_f, eligible, f_prio)
                 base = base & ~pick
                 st = dict(st, f_started=st["f_started"] | pick)
                 if use_slots:
-                    st = _acquire_slots(st, pick, f_dst, f_src, f_bytes, W)
+                    st = _acquire_slots(st, pick, onehot_f, f_src, f_bytes)
             return st
 
         def start_tasks(st):
@@ -453,14 +459,14 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             cnt = jnp.zeros(T, jnp.int32).at[e_task].add(sat)
             enabled = (cnt >= n_inputs) & ~st["t_started"]
             for _ in range(max_cores):
-                free_at = st["free"][assignment]
+                free_at = _bucket_read(onehot_aw, st["free"])
                 waiting = enabled & ~st["t_started"]
                 blocked = waiting & (cpus > free_at)
                 maxblk = jnp.full(W, NEG, jnp.float32).at[assignment].max(
                     jnp.where(blocked, priority, NEG))
                 cand = (waiting & (cpus <= free_at)
-                        & (priority >= maxblk[assignment]))
-                pick = _pick_per_bucket(assignment, W, cand, priority)
+                        & (priority >= _bucket_read(onehot_aw, maxblk)))
+                pick = _pick_per_bucket(onehot_aw, cand, priority)
                 st = dict(
                     st,
                     t_started=st["t_started"] | pick,
@@ -494,40 +500,42 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             # frontier slot order is arrival order, so the id rides
             # along as an explicit key
             neg_id = -fr.astype(jnp.float32)
-            pair_ids = jnp.arange(W * W, dtype=jnp.int32)
             if use_slots:
                 occ = st["slot_edge"] >= 0
                 dcnt = (occ.reshape(W, DOWNLOAD_SLOTS)
                         .sum(axis=1, dtype=jnp.int32))
+                # each candidate's pair occupancy, counted once on the
+                # candidate axis against the slots' pairs
                 pair_s = st["slot_src"] * W + slot_dst
-                pcnt = jnp.sum((pair_s[:, None] == pair_ids[None, :])
-                               & occ[:, None], axis=0, dtype=jnp.int32)
+                c_pcnt = jnp.sum((c_pair[:, None] == pair_s[None, :])
+                                 & occ[None, :], axis=1, dtype=jnp.int32)
             else:
                 af = (st["f_started"] & ~st["f_done"]).astype(jnp.int32)
                 dcnt = jnp.zeros(W, jnp.int32).at[f_dst].add(af * needed)
                 pcnt = jnp.zeros(W * W, jnp.int32).at[pair].add(af * needed)
+                c_pcnt = pcnt[c_pair]
             alive0 = alive
-            onehot_w = c_dst[:, None] == jnp.arange(W,
-                                                    dtype=jnp.int32)[None, :]
+            onehot_w = _onehot(c_dst, W)
             for _ in range(flow_rounds):
-                eligible = (alive & (dcnt[c_dst] < DOWNLOAD_SLOTS)
-                            & (pcnt[c_pair] < PAIR_SLOTS))
-                pick = _pick_per_bucket(c_dst, W, eligible, c_prio, neg_id)
+                eligible = (alive
+                            & (_bucket_read(onehot_w, dcnt) < DOWNLOAD_SLOTS)
+                            & (c_pcnt < PAIR_SLOTS))
+                pick = _pick_per_bucket(onehot_w, eligible, c_prio, neg_id)
                 if use_slots:
-                    st = _acquire_slots(st, pick, c_dst, c_src, c_bytes, W,
+                    st = _acquire_slots(st, pick, onehot_w, c_src, c_bytes,
                                         ids=fr)
                 # occupancy moves only by this event's own picks
                 # (completions happen at the end of the body); the picks
                 # compact to one pair per worker, so the count deltas
-                # are W-wide dense reduces, not scatters
+                # are [CF, W] dense reduces, not scatters or gathers
                 pw_pair = jnp.max(jnp.where(onehot_w & pick[:, None],
                                             c_pair[:, None], -1), axis=0,
                                   initial=-1)
                 picked_w = pw_pair >= 0
                 dcnt = dcnt + picked_w.astype(jnp.int32)
-                pcnt = pcnt + jnp.sum((pw_pair[:, None] == pair_ids[None, :])
-                                      & picked_w[:, None], axis=0,
-                                      dtype=jnp.int32)
+                c_pcnt = c_pcnt + jnp.sum((c_pair[:, None] == pw_pair[None, :])
+                                          & picked_w[None, :], axis=1,
+                                          dtype=jnp.int32)
                 alive = alive & ~pick
             picked = alive0 & ~alive
             if not use_slots:
@@ -551,16 +559,15 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             c_fin = durations[tid]
             neg_id = -fr.astype(jnp.float32)
             free = st["free"]
-            onehot_w = c_w[:, None] == jnp.arange(W,
-                                                  dtype=jnp.int32)[None, :]
+            onehot_w = _onehot(c_w, W)
             for _ in range(max_cores):
-                free_at = free[c_w]
+                free_at = _bucket_read(onehot_w, free)
                 blocked = alive & (c_cpus > free_at)
                 maxblk = _bucket_max(onehot_w,
                                      jnp.where(blocked, c_prio, NEG))
                 cand = (alive & (c_cpus <= free_at)
-                        & (c_prio >= maxblk[c_w]))
-                pick = _pick_per_bucket(c_w, W, cand, c_prio, neg_id)
+                        & (c_prio >= _bucket_read(onehot_w, maxblk)))
+                pick = _pick_per_bucket(onehot_w, cand, c_prio, neg_id)
                 # <= 1 pick per worker, so the core delta per worker is
                 # a dense masked max, not a scatter-add
                 free = free - jnp.max(jnp.where(onehot_w & pick[:, None],
@@ -733,11 +740,6 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
                 live = live & ~st["overflow"]
             return live
 
-        if use_frontier:
-            # loop-invariant worker one-hot for the dense core-release
-            # reduce in body_frontier
-            onehot_aw = (assignment[:, None]
-                         == jnp.arange(W, dtype=jnp.int32)[None, :])
         st = jax.lax.while_loop(cond, body_frontier if use_frontier else body,
                                 state0)
         makespan = jnp.max(jnp.where(st["t_done"] & task_valid,
@@ -779,6 +781,14 @@ def make_simulator(spec: GraphSpec, n_workers: int, cores,
     return run
 
 
+def _onehot(bucket, n_buckets):
+    """``bool[F, n_buckets]``: row ``f`` is True in column ``bucket[f]``.
+    Every caller's buckets lie in ``[0, n_buckets)``, so each row holds
+    exactly one True — the contract ``_bucket_read`` relies on."""
+    return bucket[:, None] == jnp.arange(n_buckets,
+                                         dtype=bucket.dtype)[None, :]
+
+
 def _bucket_max(onehot, values):
     """Per-bucket max via a dense ``[F, n_buckets]`` masked reduce.
     Semantically identical to ``full(n_buckets, NEG).at[bucket].max(v)``
@@ -790,20 +800,32 @@ def _bucket_max(onehot, values):
                    initial=NEG)
 
 
-def _pick_per_bucket(bucket, n_buckets, eligible, *keys):
-    """Lexicographic argmax per bucket.  ``keys`` are f32 arrays (higher
-    wins); final tie broken by smallest element index.  Returns bool[F]
-    with at most one True per bucket."""
-    onehot = bucket[:, None] == jnp.arange(n_buckets,
-                                           dtype=bucket.dtype)[None, :]
+def _bucket_read(onehot, table):
+    """``table[bucket]``, read back to every row of ``onehot`` by a dense
+    masked max along the bucket axis — exact for any value, because each
+    row holds exactly one True (``_onehot``).  Gather-free: a dynamic
+    gather inside the unrolled pick rounds runs about one index at a
+    time on TPU (~13 ns an index on v5e, DESIGN.md §3), while this is
+    the cost of the forward ``_bucket_max``."""
+    if jnp.issubdtype(table.dtype, jnp.floating):
+        fill = -jnp.inf
+    else:
+        fill = jnp.iinfo(table.dtype).min
+    return jnp.max(jnp.where(onehot, table[None, :], fill), axis=1)
+
+
+def _pick_per_bucket(onehot, eligible, *keys):
+    """Lexicographic argmax per bucket of ``onehot`` (``_onehot``).
+    ``keys`` are f32 arrays (higher wins); final tie broken by smallest
+    element index.  Returns bool[F] with at most one True per bucket."""
     cand = eligible
     for k in keys:
         kk = jnp.where(cand, k, NEG)
-        mb = _bucket_max(onehot, kk)[bucket]
+        mb = _bucket_read(onehot, _bucket_max(onehot, kk))
         cand = cand & (kk == mb) & (mb > NEG)
-    idx = jnp.arange(bucket.shape[0], dtype=jnp.float32)
+    idx = jnp.arange(onehot.shape[0], dtype=jnp.float32)
     ii = jnp.where(cand, -idx, NEG)
-    mb = _bucket_max(onehot, ii)[bucket]
+    mb = _bucket_read(onehot, _bucket_max(onehot, ii))
     return cand & (ii == mb)
 
 
@@ -1122,7 +1144,10 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             qworker = jnp.where(st["aw"] >= 0, st["aw"], st["pw"])
             load0 = (jnp.zeros(W, jnp.int32)
                      .at[jnp.clip(qworker, 0)].add(queued.astype(jnp.int32)))
-            new_pw = greedy_place(bspec, ready_un, cost_tw, load0, cores_j)
+            # a lane that is not due discards its placement, so it
+            # places nothing and costs the placer's loop no trip
+            new_pw = greedy_place(bspec, ready_un & due, cost_tw, load0,
+                                  cores_j)
             newly = due & (new_pw >= 0)
             return dict(
                 st,
@@ -1158,6 +1183,7 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                 pick = eligible & (rep[key_e] == e_ids)
                 return dict(st, f_started=st["f_started"] | pick)
             pair = jnp.clip(src_e, 0) * W + bucket
+            onehot = _onehot(bucket, W)
             # round-invariant eligibility base; the handled-key mask and
             # slot limits are what this event's own picks update
             base = cross & prod_e & ~key_reduce_or(key_e,
@@ -1175,16 +1201,17 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                               & ~st["f_done"]).astype(jnp.int32)
                     dcnt = jnp.zeros(W, jnp.int32).at[bucket].add(active)
                     pcnt = jnp.zeros(W * W, jnp.int32).at[pair].add(active)
-                eligible = (base & (dcnt[bucket] < DOWNLOAD_SLOTS)
+                eligible = (base
+                            & (_bucket_read(onehot, dcnt) < DOWNLOAD_SLOTS)
                             & (pcnt[pair] < PAIR_SLOTS))
                 # same key => same bucket, so one pick also dedups; all
                 # same-key edges leave the base once one of them starts
-                pick = _pick_per_bucket(bucket, W, eligible, f_prio)
+                pick = _pick_per_bucket(onehot, eligible, f_prio)
                 base = base & ~key_reduce_or(key_e, pick)[key_e]
                 st = dict(st, f_started=st["f_started"] | pick)
                 if use_slots:
-                    st = _acquire_slots(st, pick, bucket,
-                                        jnp.clip(src_e, 0), e_bytes, W)
+                    st = _acquire_slots(st, pick, onehot,
+                                        jnp.clip(src_e, 0), e_bytes)
             return st
 
         def edge_satisfied(st):
@@ -1203,15 +1230,16 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                 enabled = (cnt >= n_inputs) & ~st["t_started"] \
                     & (st["aw"] >= 0)
             bucket = jnp.clip(st["aw"], 0)
+            onehot = _onehot(bucket, W)
             for _ in range(max_cores):
-                free_at = st["free"][bucket]
+                free_at = _bucket_read(onehot, st["free"])
                 waiting = enabled & ~st["t_started"]
                 blocked = waiting & (cpus > free_at)
                 maxblk = jnp.full(W, NEG, jnp.float32).at[bucket].max(
                     jnp.where(blocked, st["ap"], NEG))
                 cand = (waiting & (cpus <= free_at)
-                        & (st["ap"] >= maxblk[bucket]))
-                pick = _pick_per_bucket(bucket, W, cand, st["ap"])
+                        & (st["ap"] >= _bucket_read(onehot, maxblk)))
+                pick = _pick_per_bucket(onehot, cand, st["ap"])
                 st = dict(
                     st,
                     t_started=st["t_started"] | pick,
@@ -1239,33 +1267,35 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             c_prio = keymax[e_obj[cid] * W + c_dst]
             c_bytes = e_bytes[cid]
             neg_id = -fr.astype(jnp.float32)
-            pair_ids = jnp.arange(W * W, dtype=jnp.int32)
             occ = st["slot_edge"] >= 0
             dcnt = occ.reshape(W, DOWNLOAD_SLOTS).sum(axis=1,
                                                       dtype=jnp.int32)
+            # each candidate's pair occupancy, counted once on the
+            # candidate axis against the slots' pairs
             pair_s = st["slot_src"] * W + slot_dst
-            pcnt = jnp.sum((pair_s[:, None] == pair_ids[None, :])
-                           & occ[:, None], axis=0, dtype=jnp.int32)
+            c_pcnt = jnp.sum((c_pair[:, None] == pair_s[None, :])
+                             & occ[None, :], axis=1, dtype=jnp.int32)
             alive0 = alive
-            onehot_w = c_dst[:, None] == jnp.arange(W,
-                                                    dtype=jnp.int32)[None, :]
+            onehot_w = _onehot(c_dst, W)
             for _ in range(flow_rounds):
-                eligible = (alive & (dcnt[c_dst] < DOWNLOAD_SLOTS)
-                            & (pcnt[c_pair] < PAIR_SLOTS))
-                pick = _pick_per_bucket(c_dst, W, eligible, c_prio, neg_id)
-                st = _acquire_slots(st, pick, c_dst, c_src, c_bytes, W,
+                eligible = (alive
+                            & (_bucket_read(onehot_w, dcnt) < DOWNLOAD_SLOTS)
+                            & (c_pcnt < PAIR_SLOTS))
+                pick = _pick_per_bucket(onehot_w, eligible, c_prio, neg_id)
+                st = _acquire_slots(st, pick, onehot_w, c_src, c_bytes,
                                     ids=fr)
                 # occupancy moves only by this event's own picks; the
                 # picks compact to one pair per worker, so the count
-                # deltas are W-wide dense reduces, not scatters
+                # deltas are [CF, W] dense reduces, not scatters or
+                # gathers
                 pw_pair = jnp.max(jnp.where(onehot_w & pick[:, None],
                                             c_pair[:, None], -1), axis=0,
                                   initial=-1)
                 picked_w = pw_pair >= 0
                 dcnt = dcnt + picked_w.astype(jnp.int32)
-                pcnt = pcnt + jnp.sum((pw_pair[:, None] == pair_ids[None, :])
-                                      & picked_w[:, None], axis=0,
-                                      dtype=jnp.int32)
+                c_pcnt = c_pcnt + jnp.sum((c_pair[:, None] == pw_pair[None, :])
+                                          & picked_w[None, :], axis=1,
+                                          dtype=jnp.int32)
                 alive = alive & ~pick
             return dict(st, fr_flow=jnp.where(alive0 & ~alive, -1, fr))
 
@@ -1283,15 +1313,15 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
             neg_id = -fr.astype(jnp.float32)
             alive0 = alive
             free = st["free"]
-            onehot_w = c_w[:, None] == jnp.arange(W,
-                                                  dtype=jnp.int32)[None, :]
+            onehot_w = _onehot(c_w, W)
             for _ in range(max_cores):
-                free_at = free[c_w]
+                free_at = _bucket_read(onehot_w, free)
                 blocked = alive & (c_cpus > free_at)
                 maxblk = _bucket_max(onehot_w,
                                      jnp.where(blocked, c_prio, NEG))
-                cand = alive & (c_cpus <= free_at) & (c_prio >= maxblk[c_w])
-                pick = _pick_per_bucket(c_w, W, cand, c_prio, neg_id)
+                cand = (alive & (c_cpus <= free_at)
+                        & (c_prio >= _bucket_read(onehot_w, maxblk)))
+                pick = _pick_per_bucket(onehot_w, cand, c_prio, neg_id)
                 # <= 1 pick per worker, so the core delta is a dense
                 # [C, W] masked max, and the started/finish writes can
                 # wait: every round shares st["now"]
