@@ -303,7 +303,10 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     splits the sweep's set-up seconds into tracing, compiling and the
     runners' host work (``setup_timer``); ``stats["group_walls"]``
     holds each group's first-call seconds (compile or cache load, plus
-    the run) and ``stats["device"]`` names what the grid ran on."""
+    the run), ``stats["backlog_peaks"]`` the most (object, destination)
+    keys that waited outside a simulation's flow frontier in each group
+    (``SimResult.backlog_peak``), and ``stats["device"]`` names what the
+    grid ran on."""
     points = grid_points(grid)
     dataset, names, t_edges = dataset_axis(grid)
     encoded, groups = encode_graph_batch(names, seed=0, bucket=True,
@@ -312,6 +315,7 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
     rows = []
     runners = {}                 # only the agreement slice is retained
     group_walls = {}             # first call (compile + run) per group
+    backlog_peaks = {}           # most flow keys waiting, per group
     est_caches = [{} for _ in groups]    # shared per bucket, not per runner
     with trace_counter() as tc, cache_counter() as cc, \
             exec_counter() as xc, setup_timer() as st:   # no cross-sweep bleed
@@ -329,8 +333,9 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
                         t0 = time.perf_counter()
                         ms, xfer = runner(points)  # compile+run [K, B, N]
                         cold_s = time.perf_counter() - t0
-                        group_walls[f"{grp.label}/W{wb}/{sched}/"
-                                    f"{netmodel}"] = cold_s
+                        label = f"{grp.label}/W{wb}/{sched}/{netmodel}"
+                        group_walls[label] = cold_s
+                        backlog_peaks[label] = runner.backlog_peak
                         if (wb == wgroups[0][0]
                                 and netmodel == grid["netmodels"][0]):
                             runners[(sched, netmodel, gi)] = (runner, cold_s,
@@ -356,6 +361,7 @@ def survey(grid, out_dir=OUT_DIR, agreement=True, engine="vmap",
         exec_save_errors=xc.save_errors,
         setup_s=st.seconds,
         group_walls=group_walls,
+        backlog_peaks=backlog_peaks,
         device=device_info(),
     )
     stats["diagnose"] = _make_diagnose(runners, grid)
@@ -402,6 +408,8 @@ def report(rows, agree_rows, stats):
     print(f"survey/exec_save_errors,0,{stats.get('exec_save_errors', 0)}")
     for phase, secs in stats.get("setup_s", {}).items():
         print(f"survey/setup_{phase}_s,0,{secs:.3f}")
+    for label, peak in stats.get("backlog_peaks", {}).items():
+        print(f"survey/backlog_peak/{label},0,{peak}")
     print(f"survey/bucket_groups,0,{stats['bucket_groups']}")
     print(f"survey/cluster_groups,0,{len(stats['cluster_groups'])}")
     print(f"survey/rows,0,{len(rows)}")

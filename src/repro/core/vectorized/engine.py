@@ -295,8 +295,9 @@ class setup_timer:
 # zero traces, zero XLA compiles.
 
 # bump to invalidate persisted entries; 2: the loop's named phases
-# (SIM_PHASES) ride in the executable's HLO
-_EXEC_FORMAT = 2
+# (SIM_PHASES) ride in the executable's HLO; 3: the flow frontier's
+# backlog, and SimResult.backlog_peak among the outputs
+_EXEC_FORMAT = 3
 _EXEC_EVENTS = {"hits": 0, "misses": 0, "save_errors": 0}
 
 

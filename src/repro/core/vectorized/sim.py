@@ -96,9 +96,12 @@ PAIR_SLOTS = 2
 # assignments, scheduler invocations and the static schedule before the
 # loop; ``sim.ready``: detecting ready flows and tasks and starting them;
 # ``sim.rates``: the network model's flow rates (the max-min waterfill);
-# ``sim.advance``: the next event time, progress and completions.
-SIM_PHASES = ("sim.schedule", "sim.ready", "sim.rates", "sim.advance")
-SCHEDULE, READY, RATES, ADVANCE = SIM_PHASES
+# ``sim.advance``: the next event time, progress and completions;
+# ``sim.backlog``, inside ``sim.ready``: the wanted downloads that found
+# the flow frontier full — their exact pick and their refill.
+SIM_PHASES = ("sim.schedule", "sim.ready", "sim.rates", "sim.advance",
+              "sim.backlog")
+SCHEDULE, READY, RATES, ADVANCE, BACKLOG = SIM_PHASES
 
 
 class SimResult(typing.NamedTuple):
@@ -106,35 +109,41 @@ class SimResult(typing.NamedTuple):
     bucketed) — a pytree, so it vmaps/jits like the old tuples.
 
     ``makespan`` is NaN whenever ``ok`` is False.  ``overflow`` is the
-    honest-failure flag of the bounded carries (flow-slot pool or ready
+    honest-failure flag of the bounded carries (flow-slot pool or task
     frontier, DESIGN.md §3): capacity was exceeded, results are invalid,
     and ``ok`` is already poisoned — widen ``frontier_caps`` or fall
-    back to ``frontier=False``.  ``n_events`` counts processed
-    completions (tasks + flows); ``n_steps`` counts ``while_loop``
-    iterations.  Same-timestamp completions are batched into one step,
-    so ``n_events / n_steps`` is the measured event-batching factor."""
+    back to ``frontier=False``.  The flow frontier never overflows: a
+    wanted download that finds it full waits in its backlog, and
+    ``backlog_peak`` is the most (object, destination) keys that waited
+    there at any step (0 where the frontier held them all).
+    ``n_events`` counts processed completions (tasks + flows);
+    ``n_steps`` counts ``while_loop`` iterations.  Same-timestamp
+    completions are batched into one step, so ``n_events / n_steps`` is
+    the measured event-batching factor."""
     makespan: jnp.ndarray      # f32
     transferred: jnp.ndarray   # f32 — bytes moved across workers
     ok: jnp.ndarray            # bool
     overflow: jnp.ndarray      # bool
     n_events: jnp.ndarray      # i32
     n_steps: jnp.ndarray       # i32
+    backlog_peak: jnp.ndarray  # i32
 
 
 def _frontier_append(fr, new_mask, ids):
     """Append ``ids[new_mask]`` into the free (``-1``) slots of the
-    bounded frontier ``fr``; returns ``(fr, overflowed)``.
+    bounded frontier ``fr``; returns ``(fr, n_left)``.
 
     Candidates fill free slots in index order, both sides ranked by
     cumsum.  Formulated as a *gather*: each free slot binary-searches
     the candidates' running count for its own rank (a full-width
     scatter here costs ~40us of fixed XLA:CPU overhead per event —
     this is a couple of vector ops plus log(N) gathers).
-    ``overflowed`` is True when candidates outnumbered free slots —
-    the caller folds it into ``ok`` so a too-small derived capacity
-    fails loudly instead of silently dropping work."""
+    ``n_left`` (i32) counts the candidates that outnumbered the free
+    slots: the task frontier folds ``n_left > 0`` into ``ok`` so a
+    too-small derived capacity fails loudly instead of silently
+    dropping work, the flow frontier keeps them in its backlog."""
     if fr.shape[0] == 0 or ids.shape[0] == 0:       # degenerate axis
-        return fr, jnp.any(new_mask)
+        return fr, jnp.sum(new_mask, dtype=jnp.int32)
     free = fr < 0
     free_rank = jnp.cumsum(free.astype(jnp.int32))          # 1-based
     cs = jnp.cumsum(new_mask.astype(jnp.int32))             # 1-based
@@ -145,8 +154,97 @@ def _frontier_append(fr, new_mask, ids):
     take = free & (free_rank <= total_new)
     src_c = jnp.clip(src, 0, ids.shape[0] - 1)
     fr = jnp.where(take, ids[src_c].astype(jnp.int32), fr)
-    overflowed = total_new > free_rank[-1]
-    return fr, overflowed
+    return fr, jnp.maximum(total_new - free_rank[-1], 0)
+
+
+def _members(fr, n):
+    """``bool[n]``: the ids the frontier ``fr`` holds."""
+    return jnp.zeros(n, bool).at[jnp.clip(fr, 0)].max(fr >= 0)
+
+
+def _run_once(pred, fn, carry):
+    """``fn(carry)`` where ``pred`` holds, else ``carry``: a
+    ``while_loop`` of at most one trip.  Under ``vmap`` a ``lax.cond``
+    with a batched predicate becomes a select that runs ``fn`` for every
+    lane in every iteration; a batched ``while_loop`` runs its body only
+    while some lane needs it, so where none does this costs the
+    predicate."""
+    def body(c):
+        return jnp.bool_(False), fn(c[1])
+    return jax.lax.while_loop(lambda c: c[0], body, (pred, carry))[1]
+
+
+def _flow_pick_rounds(st, alive, c_dst, c_src, c_prio, c_bytes, ids, *,
+                      flow_rounds, counts=None):
+    """The Appendix-A download rounds over one axis of candidate flows
+    (a flow frontier's entries, or every edge where its backlog is not
+    empty).  Each round, every destination worker under
+    ``DOWNLOAD_SLOTS`` downloads starts its best eligible candidate —
+    the highest ``c_prio``, then the smallest edge id (``ids``) — whose
+    (source, destination) pair is under ``PAIR_SLOTS``: the reference's
+    ``_start_downloads`` over that worker's wanted keys.  ``counts`` is
+    ``(dcnt[W], c_pcnt[C])`` where no slot pool holds the downloads in
+    flight; with the pool they are read off it and each pick moves into
+    it.  Returns ``(st, picked)``."""
+    if counts is None:
+        occ = st["slot_edge"] >= 0
+        W = occ.shape[0] // DOWNLOAD_SLOTS
+        dcnt = occ.reshape(W, DOWNLOAD_SLOTS).sum(axis=1, dtype=jnp.int32)
+        c_pair = c_src * W + c_dst
+        # each candidate's pair occupancy, counted once on the candidate
+        # axis against the slots' pairs
+        pair_s = (st["slot_src"] * W
+                  + np.arange(W * DOWNLOAD_SLOTS, dtype=np.int32)
+                  // DOWNLOAD_SLOTS)
+        c_pcnt = jnp.sum((c_pair[:, None] == pair_s[None, :])
+                         & occ[None, :], axis=1, dtype=jnp.int32)
+    else:
+        dcnt, c_pcnt = counts
+        W = dcnt.shape[0]
+        c_pair = c_src * W + c_dst
+    neg_id = -ids.astype(jnp.float32)
+    onehot_w = _onehot(c_dst, W)
+    alive0 = alive
+    for _ in range(flow_rounds):
+        eligible = (alive
+                    & (_bucket_read(onehot_w, dcnt) < DOWNLOAD_SLOTS)
+                    & (c_pcnt < PAIR_SLOTS))
+        pick = _pick_per_bucket(onehot_w, eligible, c_prio, neg_id)
+        if counts is None:
+            st = _acquire_slots(st, pick, onehot_w, c_src, c_bytes, ids=ids)
+        # occupancy moves only by this event's own picks (completions
+        # happen at the end of the body); the picks compact to one pair
+        # per worker, so the count deltas are [C, W] dense reduces, not
+        # scatters or gathers
+        pw_pair = jnp.max(jnp.where(onehot_w & pick[:, None],
+                                    c_pair[:, None], -1), axis=0,
+                          initial=-1)
+        picked_w = pw_pair >= 0
+        dcnt = dcnt + picked_w.astype(jnp.int32)
+        c_pcnt = c_pcnt + jnp.sum((c_pair[:, None] == pw_pair[None, :])
+                                  & picked_w[None, :], axis=1,
+                                  dtype=jnp.int32)
+        alive = alive & ~pick
+    return st, alive0 & ~alive
+
+
+def _backlog_rounds(st, fr, waiting, c_dst, c_src, c_prio, c_bytes, *,
+                    flow_rounds, counts=None):
+    """The exact pick while wanted keys wait outside the flow frontier
+    ``fr``: ``_flow_pick_rounds`` over every edge, with the frontier's
+    entries and the ``waiting`` key representatives (``bool[E]``) as
+    candidates — what the rounds over a frontier that held them all
+    would pick.  Returns ``(st, fr less its picked entries, members,
+    picked)``: ``members`` marks the frontier's entries before the
+    picks."""
+    E = waiting.shape[0]
+    members = _members(fr, E)
+    st, picked = _flow_pick_rounds(
+        st, members | waiting, c_dst, c_src, c_prio, c_bytes,
+        jnp.arange(E, dtype=jnp.int32), flow_rounds=flow_rounds,
+        counts=counts)
+    fr = jnp.where((fr >= 0) & picked[jnp.clip(fr, 0)], -1, fr)
+    return st, fr, members, picked
 
 
 def _resolve_frontier(frontier, *, simple: bool, use_slots: bool,
@@ -290,9 +388,13 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
     capacities derived per bucket by ``specs.frontier_caps_for`` or
     overridden via ``frontier_caps=(CF, CT)``.  The flow/task pick
     rounds then touch O(frontier) entries instead of O(E)/O(T), and
-    with ``flow_slots`` the loop carries no per-edge state at all.  A
-    frontier overflow poisons ``ok`` (``SimResult.overflow`` — honest
-    failure, never silent truncation).
+    with ``flow_slots`` the loop carries no per-edge state at all.  The
+    flow cap sizes a window, not a limit: a candidate flow that finds
+    the list full waits in a backlog of (object, destination) keys,
+    and while any waits the pick runs exactly over every edge
+    (``_backlog_rounds``; ``SimResult.backlog_peak``).  A task frontier
+    overflow poisons ``ok`` (``SimResult.overflow`` — honest failure,
+    never silent truncation).
 
     ``flow_slots=False`` keeps the legacy per-edge ``f32[E]`` network
     state; ``waterfill_impl`` routes the max-min solver (``"jnp"`` |
@@ -365,6 +467,9 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
         else:
             # an explicit override never exceeds the axis itself
             CF, CT = min(frontier_caps[0], E), min(frontier_caps[1], T)
+        # the flow list holds distinct reps, so one of E entries can
+        # hold every key at once: only a narrower one keeps a backlog
+        windowed = use_frontier and not simple and CF < E
         t_ids = jnp.arange(T, dtype=jnp.int32)
 
         state0 = dict(
@@ -394,14 +499,20 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             state0["f_rem"] = f_bytes
         if use_frontier:
             state0.setdefault("overflow", jnp.bool_(False))
-            fr_task0, ov0 = _frontier_append(jnp.full(CT, -1, jnp.int32),
-                                             (n_inputs <= 0) & task_valid,
-                                             t_ids)
+            fr_task0, left0 = _frontier_append(jnp.full(CT, -1, jnp.int32),
+                                               (n_inputs <= 0) & task_valid,
+                                               t_ids)
             state0.update(sat_cnt=jnp.zeros(T, jnp.int32), fr_task=fr_task0,
-                          overflow=state0["overflow"] | ov0)
+                          overflow=state0["overflow"] | (left0 > 0))
             if not simple:
                 state0.update(in_cnt=jnp.zeros(T, jnp.int32),
                               fr_flow=jnp.full(CF, -1, jnp.int32))
+            if windowed:
+                # the flow frontier's backlog: keys that found it full,
+                # their count, and the most that ever waited at once
+                state0.update(key_wait=jnp.zeros(O * W, bool),
+                              n_wait=jnp.int32(0),
+                              backlog_peak=jnp.int32(0))
             if use_slots:
                 state0["transferred"] = jnp.float32(0.0)
 
@@ -482,7 +593,10 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             download priority stays *exact*: one O(E) scatter-max into
             the (obj, dst) key space per event, gathered only at the CF
             candidates — the key max ranges over all same-key edges,
-            frontier members or not, exactly like the baseline."""
+            frontier members or not, exactly like the baseline.  While
+            keys wait in the backlog, the pick runs over every edge
+            instead (``_backlog_rounds``), and the keys it leaves
+            waiting refill the frontier's free entries."""
             ready_t = st["in_cnt"] >= n_inputs
             raw = jnp.where(edge_valid,
                             prio_e + READY_BOOST
@@ -490,59 +604,53 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
             keymax = jnp.full(O * W, NEG, jnp.float32).at[key].max(raw)
             fr = st["fr_flow"]
             cid = jnp.clip(fr, 0)
-            alive = fr >= 0
             c_dst = f_dst[cid]
             c_src = f_src[cid]
-            c_pair = c_src * W + c_dst
-            c_prio = keymax[key[cid]]
-            c_bytes = f_bytes[cid]
-            # the baseline breaks priority ties by smallest edge id;
-            # frontier slot order is arrival order, so the id rides
-            # along as an explicit key
-            neg_id = -fr.astype(jnp.float32)
-            if use_slots:
-                occ = st["slot_edge"] >= 0
-                dcnt = (occ.reshape(W, DOWNLOAD_SLOTS)
-                        .sum(axis=1, dtype=jnp.int32))
-                # each candidate's pair occupancy, counted once on the
-                # candidate axis against the slots' pairs
-                pair_s = st["slot_src"] * W + slot_dst
-                c_pcnt = jnp.sum((c_pair[:, None] == pair_s[None, :])
-                                 & occ[None, :], axis=1, dtype=jnp.int32)
-            else:
+            alive = fr >= 0
+            if windowed:
+                waiting = st["n_wait"] > 0
+                alive = alive & ~waiting
+            counts = None
+            if not use_slots:
                 af = (st["f_started"] & ~st["f_done"]).astype(jnp.int32)
                 dcnt = jnp.zeros(W, jnp.int32).at[f_dst].add(af * needed)
                 pcnt = jnp.zeros(W * W, jnp.int32).at[pair].add(af * needed)
-                c_pcnt = pcnt[c_pair]
-            alive0 = alive
-            onehot_w = _onehot(c_dst, W)
-            for _ in range(flow_rounds):
-                eligible = (alive
-                            & (_bucket_read(onehot_w, dcnt) < DOWNLOAD_SLOTS)
-                            & (c_pcnt < PAIR_SLOTS))
-                pick = _pick_per_bucket(onehot_w, eligible, c_prio, neg_id)
-                if use_slots:
-                    st = _acquire_slots(st, pick, onehot_w, c_src, c_bytes,
-                                        ids=fr)
-                # occupancy moves only by this event's own picks
-                # (completions happen at the end of the body); the picks
-                # compact to one pair per worker, so the count deltas
-                # are [CF, W] dense reduces, not scatters or gathers
-                pw_pair = jnp.max(jnp.where(onehot_w & pick[:, None],
-                                            c_pair[:, None], -1), axis=0,
-                                  initial=-1)
-                picked_w = pw_pair >= 0
-                dcnt = dcnt + picked_w.astype(jnp.int32)
-                c_pcnt = c_pcnt + jnp.sum((c_pair[:, None] == pw_pair[None, :])
-                                          & picked_w[None, :], axis=1,
-                                          dtype=jnp.int32)
-                alive = alive & ~pick
-            picked = alive0 & ~alive
+                counts = (dcnt, pcnt[c_src * W + c_dst])
+            # the baseline breaks priority ties by smallest edge id;
+            # frontier slot order is arrival order, so the id rides
+            # along as an explicit key
+            st, picked = _flow_pick_rounds(
+                st, alive, c_dst, c_src, keymax[key[cid]], f_bytes[cid], fr,
+                flow_rounds=flow_rounds, counts=counts)
             if not use_slots:
                 # one deferred scatter for all rounds' starts
                 st = dict(st, f_started=st["f_started"].at[
                     jnp.where(picked, fr, E)].set(True, mode="drop"))
-            return dict(st, fr_flow=jnp.where(picked, -1, fr))
+            st = dict(st, fr_flow=jnp.where(picked, -1, fr))
+            if not windowed:
+                return st
+
+            def drain(sub):
+                with jax.named_scope(BACKLOG):
+                    wait_e = needed & sub["key_wait"][key]
+                    sub, fr, _, picked = _backlog_rounds(
+                        sub, sub["fr_flow"], wait_e, f_dst, f_src,
+                        keymax[key], f_bytes, flow_rounds=flow_rounds,
+                        counts=None if use_slots else (dcnt, pcnt[pair]))
+                    if not use_slots:
+                        sub = dict(sub, f_started=sub["f_started"] | picked)
+                    left = wait_e & ~picked
+                    fr, n_left = _frontier_append(fr, left, e_ids)
+                    key_wait = jnp.zeros(O * W, bool).at[key].max(
+                        left & ~_members(fr, E))
+                    return dict(sub, fr_flow=fr, key_wait=key_wait,
+                                n_wait=n_left)
+
+            moved = ("fr_flow", "key_wait", "n_wait",
+                     *(("slot_edge", "slot_src", "slot_rem", "overflow")
+                       if use_slots else ("f_started",)))
+            return dict(st, **_run_once(waiting, drain,
+                                        {k: st[k] for k in moved}))
 
         def start_tasks_frontier(st):
             """Appendix-A start rounds over the bounded enabled-task
@@ -722,21 +830,37 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
                 sat_cnt = st["sat_cnt"] + both[:T]
             newly_en = ((sat_cnt >= n_inputs) & (st["sat_cnt"] < n_inputs)
                         & task_valid)
-            fr_task, ov = _frontier_append(st["fr_task"], newly_en, t_ids)
-            st = dict(st, sat_cnt=sat_cnt, fr_task=fr_task)
+            fr_task, left_t = _frontier_append(st["fr_task"], newly_en,
+                                               t_ids)
+            st = dict(st, sat_cnt=sat_cnt, fr_task=fr_task,
+                      overflow=st["overflow"] | (left_t > 0))
             if not simple:
                 new_flow = needed & t_newly[prod_task_e]
-                fr_flow, ov_f = _frontier_append(st["fr_flow"], new_flow,
-                                                 e_ids)
+                fr_flow, n_left = _frontier_append(st["fr_flow"], new_flow,
+                                                   e_ids)
                 st = dict(st, in_cnt=st["in_cnt"] + both[T:], fr_flow=fr_flow)
-                ov = ov | ov_f
-            return dict(st, overflow=st["overflow"] | ov)
+
+            if windowed:
+                def defer(key_wait):
+                    # the new keys that found the frontier full wait
+                    with jax.named_scope(BACKLOG):
+                        left = new_flow & ~_members(fr_flow, E)
+                        return key_wait | jnp.zeros(O * W, bool).at[
+                            key].max(left)
+
+                n_wait = st["n_wait"] + n_left
+                st = dict(st, key_wait=_run_once(n_left > 0, defer,
+                                                 st["key_wait"]),
+                          n_wait=n_wait,
+                          backlog_peak=jnp.maximum(st["backlog_peak"],
+                                                   n_wait))
+            return st
 
         def cond(st):
             live = (~jnp.all(st["t_done"])) & (st["steps"] < steps_cap)
             if use_frontier:
-                # an overflowed frontier is no longer sound — stop and
-                # report (ok is already poisoned by the flag)
+                # an overflowed task frontier is no longer sound — stop
+                # and report (ok is already poisoned by the flag)
                 live = live & ~st["overflow"]
             return live
 
@@ -754,7 +878,8 @@ def make_bucket_simulator(n_workers: int, cores, netmodel: str = "maxmin",
         ok = ok & ~overflow
         makespan = jnp.where(ok, makespan, jnp.nan)
         return SimResult(makespan, transferred, ok, overflow,
-                         st["n_events"], st["steps"])
+                         st["n_events"], st["steps"],
+                         st.get("backlog_peak", jnp.int32(0)))
 
     return run
 
@@ -837,8 +962,8 @@ def _check_ok(ok, context: str, overflow=None):
         if overflow is not None and np.asarray(overflow).any():
             nov = int(np.asarray(overflow).sum())
             raise RuntimeError(
-                f"{context}: {nov}/{ok.size} simulation(s) overflowed a "
-                f"bounded ready frontier (DESIGN.md §3) — widen "
+                f"{context}: {nov}/{ok.size} simulation(s) overflowed the "
+                f"bounded task frontier (DESIGN.md §3) — widen "
                 f"`frontier_caps` or run with `frontier=False`")
         raise RuntimeError(
             f"{context}: {bad}/{ok.size} simulation(s) exhausted their "
@@ -935,12 +1060,16 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
     pass per event feeds bounded candidate lists, and everything
     event-rate-dependent (flow pick rounds, Appendix-A start rounds,
     the greedy invoke's per-key views) runs on O(frontier)/O(S)
-    entries.  Tie-break caveat (greedy only): the dedup representative
-    of an (object, destination) key is pinned when the key first
-    becomes wanted, so an exact cross-key priority tie can order picks
-    by a different edge id than the baseline when a same-key edge with
-    a smaller id becomes wanted later; static schedulers assign every
-    consumer at one apply event, so their tie-breaks are exact.
+    entries.  A wanted key that finds the flow list full is not
+    queued: the detection pass wants it again in the next iteration,
+    and while any key waits the pick runs exactly over every edge, as
+    in the static loop.  Tie-break caveat (greedy only): the dedup
+    representative of an (object, destination) key is pinned when the
+    key enters the flow list, so an exact cross-key priority tie can
+    order picks by a different edge id than the baseline when a
+    same-key edge with a smaller id becomes wanted later; static
+    schedulers assign every consumer at one apply event, so their
+    tie-breaks are exact.
     """
     if scheduler not in VEC_SCHEDULERS:
         raise KeyError(f"unknown vectorized scheduler {scheduler!r} "
@@ -1028,6 +1157,9 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
         else:
             # an explicit override never exceeds the axis itself
             CF, CT = min(frontier_caps[0], E), min(frontier_caps[1], T)
+        # the flow list holds distinct reps, so one of E entries can
+        # hold every key at once: only a narrower one keeps a backlog
+        windowed = use_frontier and use_slots and CF < E
         t_ids = jnp.arange(T, dtype=jnp.int32)
 
         state0 = dict(
@@ -1074,6 +1206,8 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                 if use_slots:
                     state0.update(fr_flow=jnp.full(CF, -1, jnp.int32),
                                   transferred=jnp.float32(0.0))
+                if windowed:
+                    state0["backlog_peak"] = jnp.int32(0)
 
         # ------------------------------------------------ shared views
         def edge_views(st):
@@ -1250,54 +1384,26 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                 )
             return st
 
-        def start_flows_frontier(st, keymax):
+        def start_flows_frontier(st, keymax, waiting):
             """Max-min flow picks over the pinned candidate list; the
             slot pool is required (in-flight state and the Appendix-A
             occupancy live there).  ``keymax`` is this event's priority
             scatter-max from the detection pass, gathered only at the
             CF candidates; ``-edge_id`` reproduces the baseline
             tie-break (exact for static schedulers, see factory
-            docstring for the greedy caveat)."""
+            docstring for the greedy caveat).  A lane whose keys are
+            ``waiting`` outside the list picks nothing here: the
+            backlog's exact pick serves it."""
             fr = st["fr_flow"]
             cid = jnp.clip(fr, 0)
-            alive = fr >= 0
             c_dst = jnp.clip(st["aw"][e_task[cid]], 0)
-            c_src = jnp.clip(st["aw"][prod_task_e[cid]], 0)
-            c_pair = c_src * W + c_dst
-            c_prio = keymax[e_obj[cid] * W + c_dst]
-            c_bytes = e_bytes[cid]
-            neg_id = -fr.astype(jnp.float32)
-            occ = st["slot_edge"] >= 0
-            dcnt = occ.reshape(W, DOWNLOAD_SLOTS).sum(axis=1,
-                                                      dtype=jnp.int32)
-            # each candidate's pair occupancy, counted once on the
-            # candidate axis against the slots' pairs
-            pair_s = st["slot_src"] * W + slot_dst
-            c_pcnt = jnp.sum((c_pair[:, None] == pair_s[None, :])
-                             & occ[None, :], axis=1, dtype=jnp.int32)
-            alive0 = alive
-            onehot_w = _onehot(c_dst, W)
-            for _ in range(flow_rounds):
-                eligible = (alive
-                            & (_bucket_read(onehot_w, dcnt) < DOWNLOAD_SLOTS)
-                            & (c_pcnt < PAIR_SLOTS))
-                pick = _pick_per_bucket(onehot_w, eligible, c_prio, neg_id)
-                st = _acquire_slots(st, pick, onehot_w, c_src, c_bytes,
-                                    ids=fr)
-                # occupancy moves only by this event's own picks; the
-                # picks compact to one pair per worker, so the count
-                # deltas are [CF, W] dense reduces, not scatters or
-                # gathers
-                pw_pair = jnp.max(jnp.where(onehot_w & pick[:, None],
-                                            c_pair[:, None], -1), axis=0,
-                                  initial=-1)
-                picked_w = pw_pair >= 0
-                dcnt = dcnt + picked_w.astype(jnp.int32)
-                c_pcnt = c_pcnt + jnp.sum((c_pair[:, None] == pw_pair[None, :])
-                                          & picked_w[None, :], axis=1,
-                                          dtype=jnp.int32)
-                alive = alive & ~pick
-            return dict(st, fr_flow=jnp.where(alive0 & ~alive, -1, fr))
+            alive = fr >= 0 if waiting is None else (fr >= 0) & ~waiting
+            st, picked = _flow_pick_rounds(
+                st, alive, c_dst,
+                jnp.clip(st["aw"][prod_task_e[cid]], 0),
+                keymax[e_obj[cid] * W + c_dst], e_bytes[cid], fr,
+                flow_rounds=flow_rounds)
+            return dict(st, fr_flow=jnp.where(picked, -1, fr))
 
         def start_tasks_frontier(st):
             """Appendix-A start rounds over the bounded enabled list —
@@ -1449,13 +1555,13 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                     ready_t[e_task].astype(jnp.float32)
                 raw = jnp.where(assigned, raw, NEG)
                 keymax = jnp.full(F, NEG, jnp.float32).at[key_e].max(raw)
+                # key_q marks the keys whose rep is on the flow list or
+                # has started; a wanted key that found the list full is
+                # wanted again, so it is offered again in each iteration
                 want = cross & prod_e & ~st["key_q"][key_e]
                 rep = (jnp.full(F, E, jnp.int32)
                        .at[key_e].min(jnp.where(want, e_ids, E)))
                 new_flow = want & (rep[key_e] == e_ids)
-                # rep < E exactly marks the keys that just queued a rep,
-                # so key_q updates as a dense [F] mask — no scatter
-                st = dict(st, key_q=st["key_q"] | (rep < E))
                 sat = assigned & ((prod_e & (src_e == aw_e))
                                   | st["key_done"][key_e])
                 sat_cnt = (jnp.zeros(T, jnp.int32)
@@ -1463,23 +1569,53 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
                 enabled = ((sat_cnt >= n_inputs) & (st["aw"] >= 0)
                            & ~st["t_started"])
                 if use_slots:
-                    fr_flow, ov = _frontier_append(st["fr_flow"], new_flow,
-                                                   e_ids)
+                    fr_flow, n_left = _frontier_append(st["fr_flow"],
+                                                       new_flow, e_ids)
+                    # rep < E exactly marks the keys that just queued a
+                    # rep, so where every one fit key_q updates as a
+                    # dense [F] mask — no scatter
+                    queued = rep < E
+                    waiting = None
+                    if windowed:
+                        waiting = n_left > 0
+                        queued = queued & ~waiting
+                        st = dict(st, backlog_peak=jnp.maximum(
+                            st["backlog_peak"], n_left))
                     st = dict(st, fr_flow=fr_flow,
-                              overflow=st["overflow"] | ov)
+                              key_q=st["key_q"] | queued)
                 else:
                     # simple netmodel: no slot limits — pinned reps
                     # start the moment they become wanted, exactly the
                     # baseline's immediate-start semantics
-                    st = dict(st, f_started=st["f_started"] | new_flow)
+                    st = dict(st, key_q=st["key_q"] | (rep < E),
+                              f_started=st["f_started"] | new_flow)
             else:
                 enabled = (st["aw"] >= 0) & ~st["t_started"]
             new_en = enabled & ~st["enq_t"]
-            fr_task, ov_t = _frontier_append(st["fr_task"], new_en, t_ids)
+            fr_task, left_t = _frontier_append(st["fr_task"], new_en, t_ids)
             st = dict(st, fr_task=fr_task, enq_t=st["enq_t"] | new_en,
-                      overflow=st["overflow"] | ov_t)
+                      overflow=st["overflow"] | (left_t > 0))
             if E > 0 and use_slots:
-                st = start_flows_frontier(st, keymax)
+                st = start_flows_frontier(st, keymax, waiting)
+
+            if windowed:
+                def drain(sub):
+                    # the exact pick over the list and the waiting reps;
+                    # the keys that entered the list or started leave
+                    # the backlog, the rest are wanted again next time
+                    with jax.named_scope(BACKLOG):
+                        sub, fr, members, picked = _backlog_rounds(
+                            sub, sub["fr_flow"], new_flow, jnp.clip(aw_e, 0),
+                            jnp.clip(src_e, 0), keymax[key_e], e_bytes,
+                            flow_rounds=flow_rounds)
+                        return dict(sub, fr_flow=fr, key_q=sub["key_q"]
+                                    | jnp.zeros(F, bool).at[key_e].max(
+                                        new_flow & (members | picked)))
+
+                moved = ("fr_flow", "key_q", "slot_edge", "slot_src",
+                         "slot_rem", "overflow")
+                st = dict(st, **_run_once(waiting, drain,
+                                          {k: st[k] for k in moved}))
             return start_tasks_frontier(st), key_e
 
         def advance_frontier(st, rates, key_e):
@@ -1548,8 +1684,8 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
         def cond(st):
             live = (~jnp.all(st["t_done"])) & (st["steps"] < steps_cap)
             if use_frontier:
-                # an overflowed frontier is no longer sound — stop and
-                # report (ok is already poisoned by the flag)
+                # an overflowed task frontier is no longer sound — stop
+                # and report (ok is already poisoned by the flag)
                 live = live & ~st["overflow"]
             return live
 
@@ -1566,7 +1702,8 @@ def make_bucket_dynamic_simulator(n_workers: int, cores,
         ok = ok & ~overflow
         makespan = jnp.where(ok, makespan, jnp.nan)
         return SimResult(makespan, transferred, ok, overflow,
-                         st["n_events"], st["steps"])
+                         st["n_events"], st["steps"],
+                         st.get("backlog_peak", jnp.int32(0)))
 
     return run
 
@@ -1797,10 +1934,13 @@ class BucketedGridRunner:
         """Same point dicts as ``DynamicGridRunner``; returns
         ``(makespans f32[B, N], transferred f32[B, N])`` with the graph
         axis in ``self.names`` order — with a leading cluster axis
-        (``f32[K, B, N]``) when built with a ``[K, W]`` cores matrix."""
+        (``f32[K, B, N]``) when built with a ``[K, W]`` cores matrix.
+        ``backlog_peak`` keeps the call's largest
+        ``SimResult.backlog_peak``."""
         res = self._execute(*self.grid_arrays(points))
         _check_ok(res.ok, f"{type(self).__name__}({self.names!r}, "
                           f"{self.scheduler!r})", res.overflow)
+        self.backlog_peak = int(np.max(res.backlog_peak))
         ms, xfer = np.asarray(res.makespan), np.asarray(res.transferred)
         if self._single_cluster:
             return ms[0], xfer[0]

@@ -241,10 +241,11 @@ def frontier_cap(n: int, floor: int = FRONTIER_FLOOR) -> int:
     frontier cannot overflow, so frontier mode is exactly the baseline
     with compact picks); large buckets get ``n // 4`` rounded up to
     ``PAD_MULTIPLE``, bounding the per-event pick work the same way the
-    ``DOWNLOAD_SLOTS * W`` pool bounds in-flight flows.  A frontier
-    overflow at runtime is recorded and poisons ``ok`` (honest failure,
-    never silent truncation); callers can widen via the factories'
-    ``frontier_caps`` override."""
+    ``DOWNLOAD_SLOTS * W`` pool bounds in-flight flows.  A task
+    frontier overflow at runtime is recorded and poisons ``ok`` (honest
+    failure, never silent truncation); callers can widen via the
+    factories' ``frontier_caps`` override.  A full flow frontier only
+    makes wanted downloads wait (``FLOW_FRONTIER_PER_WORKER``)."""
     if n <= floor:
         return n
     return min(n, max(floor, round_up(n // 4)))
@@ -254,12 +255,13 @@ def frontier_cap(n: int, floor: int = FRONTIER_FLOOR) -> int:
 # with the number of destination workers, so the flow frontier holds at
 # least this many per worker — four DOWNLOAD_SLOTS pools.  No tighter
 # bound exists: every (object, destination) key of the bucket can be
-# pending at once, so only E bounds the list.  This is a sizing rule,
-# and an overflow still fails loudly.  The shape-only cap (256 for
-# E=992) overflowed on the paper's 32-worker clusters.  Over the full
-# survey grid on 32x4 (T512 bucket, maxmin, all six schedulers, all 24
-# points), the worst case is crossvx: it fits 320 under blevel and mcp,
-# 384 under random, and 256 under the rest, against 16 * 32 = 512
+# pending at once (~990 of a 32 x 32 shuffle's 1,056 on W=32, ~25,000
+# of the published 160 x 160 one).  So the flow cap sizes a window, not
+# a limit: a wanted key that finds the list full waits in the loop's
+# backlog and enters later, and while any waits the pick runs exactly
+# over every edge (sim._backlog_rounds).  The window sets what an
+# iteration with no waiting key costs.  The task frontier is the one
+# that still fails loudly on overflow.
 FLOW_FRONTIER_PER_WORKER = 16
 
 
@@ -267,7 +269,9 @@ def frontier_caps_for(shape, floor: int = FRONTIER_FLOOR, *,
                       n_workers: int):
     """``(flow_cap, task_cap)`` for a bucket shape ``(T, O, E)`` — the
     derived sizes of the candidate-flow and ready-task frontiers; the
-    flow cap also covers ``FLOW_FRONTIER_PER_WORKER * n_workers``."""
+    flow cap, the window of candidate flows an iteration picks from
+    while none waits, also covers ``FLOW_FRONTIER_PER_WORKER *
+    n_workers``."""
     T, _O, E = shape
     flow_cap = max(frontier_cap(E, floor),
                    FLOW_FRONTIER_PER_WORKER * n_workers)

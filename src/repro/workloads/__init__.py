@@ -11,17 +11,17 @@ from .recipes import (Recipe, RECIPE_FAMILIES, PEGASUS_EQUIVALENT,
                       instance_rng_seed, make_instance, parse_instance,
                       sample_dist)
 from .wfformat import load_wfformat, dump_wfformat, save_wfformat
-from .datasets import (Manifest, MANIFESTS, WFCOMMONS_MINI, build_dataset,
-                       compute_bucket_edges, compute_w_buckets,
+from .datasets import (Manifest, MANIFESTS, WFCOMMONS_MINI, ESTEE_MAPREDUCE,
+                       build_dataset, compute_bucket_edges, compute_w_buckets,
                        default_manifest, get_manifest, w_bucket)
 
 __all__ = [
     "Recipe", "RECIPE_FAMILIES", "PEGASUS_EQUIVALENT", "instance_rng_seed",
     "make_instance", "parse_instance", "sample_dist",
     "load_wfformat", "dump_wfformat", "save_wfformat",
-    "Manifest", "MANIFESTS", "WFCOMMONS_MINI", "build_dataset",
-    "compute_bucket_edges", "compute_w_buckets", "default_manifest",
-    "get_manifest", "w_bucket", "resolve_workload",
+    "Manifest", "MANIFESTS", "WFCOMMONS_MINI", "ESTEE_MAPREDUCE",
+    "build_dataset", "compute_bucket_edges", "compute_w_buckets",
+    "default_manifest", "get_manifest", "w_bucket", "resolve_workload",
 ]
 
 

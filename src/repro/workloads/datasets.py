@@ -56,7 +56,17 @@ WFCOMMONS_MINI = Manifest(
     description="3 recipe families x 2 scales (CI survey smoke)",
 )
 
-MANIFESTS = {m.name: m for m in (WFCOMMONS_MINI,)}
+# the ESTEE survey's irw MapReduce shuffle cut to 32 maps x 32 reduces
+# (+1 collector): the whole shuffle becomes wanted at once, so on W=32
+# keys wait outside the flow frontier (the benchmark's
+# estee-w32-mapreduce configuration)
+ESTEE_MAPREDUCE = Manifest(
+    name="estee-mapreduce",
+    instances=("mapreduce-65-s0",),
+    description="irw mapreduce, 32 maps x 32 reduces (all-to-all shuffle)",
+)
+
+MANIFESTS = {m.name: m for m in (WFCOMMONS_MINI, ESTEE_MAPREDUCE)}
 
 
 def default_manifest(per_family: int = 1) -> Manifest:

@@ -1,5 +1,6 @@
-"""The program's own phases: the dynamic event loop's four named scopes
-(``SIM_PHASES``), which the compiler keeps in each op's ``op_name`` so a
+"""The program's own phases: the dynamic event loop's named scopes
+(``SIM_PHASES``: four in order, and the flow frontier's backlog inside
+``sim.ready``), which the compiler keeps in each op's ``op_name`` so a
 device trace can charge the op to its phase, and set-up seconds by
 phase on the host (``setup_seconds`` / ``setup_timer``), counted once
 however JAX's compile steps and the runner's own spans nest."""
@@ -32,15 +33,28 @@ POINTS = [dict(imode="exact", bandwidth=32 * MiB, msd=0.0,
 def test_loop_phases_are_named_in_the_lowering(scheduler, netmodel,
                                                frontier):
     g = tvd.mini_fork()
-    run = make_bucket_dynamic_simulator(4, 2, scheduler=scheduler,
-                                        netmodel=netmodel,
-                                        frontier=frontier)
     d, s = encode_imode(g, "user")
-    text = jax.jit(run).lower(as_bucketed(encode_graph(g)), d, s,
-                              np.float32(0.1), np.float32(0.05)
-                              ).as_text(debug_info=True)
-    for phase in SIM_PHASES:
+
+    def lowered(frontier_caps=None):
+        run = make_bucket_dynamic_simulator(4, 2, scheduler=scheduler,
+                                            netmodel=netmodel,
+                                            frontier=frontier,
+                                            frontier_caps=frontier_caps)
+        return jax.jit(run).lower(as_bucketed(encode_graph(g)), d, s,
+                                  np.float32(0.1), np.float32(0.05)
+                                  ).as_text(debug_info=True)
+
+    text = lowered()
+    schedule, ready, rates, advance, backlog = SIM_PHASES
+    for phase in (schedule, ready, rates, advance):
         assert f"while/body/{phase}/" in text, phase
+    # the flow frontier's backlog runs inside sim.ready, in a loop of at
+    # most one trip, where a max-min flow list narrower than E keeps one;
+    # this graph's derived list holds all of E
+    nested = f"while/body/{ready}/while/body/{backlog}/"
+    assert backlog not in text
+    assert (nested in lowered((4, 32))) == (netmodel == "maxmin"
+                                            and frontier is None)
     # the schedule before the loop: blevel's order and placement, or
     # greedy's priorities
     assert f"/{SIM_PHASES[0]}/" in text.replace("while/body/", "")

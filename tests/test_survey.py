@@ -101,6 +101,19 @@ def test_survey_graphs_cover_every_family():
     assert len(names) == sum(min(2, len(v)) for v in SURVEY_GRAPHS.values())
 
 
+def test_report_prints_the_backlog_peak_of_each_group(capsys):
+    stats = dict(compiles=2, bucket_groups=2, cluster_groups=["W32:32x4"],
+                 dataset="estee-mapreduce", t_edges=(96,), cache_hits=0,
+                 device=dict(platform="cpu", kind="cpu", count=1),
+                 setup_s=dict(trace=1.0, compile=0.5, host=0.25),
+                 backlog_peaks={"T96/W32/greedy/maxmin": 478,
+                                "T96/W32/greedy/simple": 0})
+    survey.report([], [], stats)
+    out = capsys.readouterr().out.splitlines()
+    assert {"survey/backlog_peak/T96/W32/greedy/maxmin,0,478",
+            "survey/backlog_peak/T96/W32/greedy/simple,0,0"} <= set(out)
+
+
 def test_encode_graph_batch_builds_specs_once():
     batch = encode_graph_batch(["fastcrossv", "sipht"], seed=0)
     g, spec = batch["fastcrossv"]

@@ -148,6 +148,17 @@ def test_get_manifest():
     assert "wfcommons-mini" in MANIFESTS
 
 
+def test_estee_mapreduce_manifest_is_the_32_by_32_shuffle():
+    """The survey's irw MapReduce cut to 32 maps x 32 reduces: every
+    reduce reads one shard of every map, so O = E = 32 * 32 + 32."""
+    man = get_manifest("estee-mapreduce")
+    assert man.instances == ("mapreduce-65-s0",)
+    g, = build_dataset(man).values()
+    spec = encode_graph(g)
+    assert (spec.T, spec.O, spec.E) == (65, 1056, 1056)
+    assert compute_bucket_edges(man) == (96,)
+
+
 # ----------------------------------------------- adaptive bucket edges
 
 
